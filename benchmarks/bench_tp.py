@@ -100,7 +100,18 @@ print("TPBENCH_JSON:" + json.dumps({
 
 def collect(full: bool = False) -> dict:
     """Run the 8-device sweep in a subprocess and return its payload
-    (provenance attached from this process — same backend/mode)."""
+    (provenance attached from this process — same backend/mode).
+
+    The forced host devices exist only on the CPU backend.  On an
+    accelerator this process already holds the chip and a child could
+    not take it, so the sweep is reported as not measured there (the
+    4-chip tp path is ``chip_smoke.py --chips 4``)."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return {"sweep": [], "provenance": provenance(),
+                "not_measured": "the host-device sweep runs only on the "
+                                "CPU backend"}
     env = {**__import__("os").environ, "PYTHONPATH": "src",
            "TP_BENCH_REPS": "20" if full else "10"}
     out = subprocess.run([sys.executable, "-c", CHILD],

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (bench_latency, bench_maintenance, bench_placement,
                bench_ring_lookup, bench_serve, bench_tp, fig3_planetlab_bw,
                fig4_hpc_bw, fig5_latency, fig7_analytical, fig8_quarantine,
@@ -44,6 +46,7 @@ def main() -> None:
                     help="comma-separated subset of " + ",".join(ALL))
     args = ap.parse_args()
     names = list(ALL) if not args.only else args.only.split(",")
+    enable_compile_cache()
     header()
     for name in names:
         ALL[name](full=args.full)

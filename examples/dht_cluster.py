@@ -14,7 +14,10 @@ from repro.core.tuning import EdraParams
 from repro.dht.d1ht_node import D1HTPeer
 from repro.dht.data import BlockStore
 from repro.dht.des import LanDelay, SimNet
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import ElasticController, Membership, Placement
+
+enable_compile_cache()
 
 N = 64
 net = SimNet(LanDelay(), seed=0)

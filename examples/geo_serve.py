@@ -19,9 +19,12 @@ import sys
 import jax
 
 from repro.configs import get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.runtime import LatencyAware, Membership, RingSuccessor, Topology
 from repro.serve import Request, ServeCluster
+
+enable_compile_cache()
 
 N_PER_REGION = 4
 REQUESTS = 12

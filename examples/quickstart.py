@@ -9,6 +9,9 @@ from repro.core import analysis, build_ring
 from repro.core.tuning import EdraParams
 from repro.dht import ChurnConfig, run_churn
 from repro.kernels.ring_lookup.ops import ring_lookup
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. A consistent-hashing ring with full routing tables (paper §III)
 ring = build_ring(1000, seed=0)

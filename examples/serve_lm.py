@@ -14,9 +14,12 @@ import jax
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.runtime import Membership
 from repro.serve import Request, ServeCluster
+
+enable_compile_cache()
 
 cfg = get_smoke_config("qwen2.5-3b")
 model = Model(cfg)

@@ -11,6 +11,7 @@ import jax
 
 from repro.configs.base import ModelConfig
 from repro.data import DataConfig, Prefetcher, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.optim import adamw
 from repro.runtime import FailoverConfig, Membership
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = model_tiny() if args.tiny else model_100m()
     model = Model(cfg)

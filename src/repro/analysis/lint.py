@@ -303,8 +303,7 @@ class _Indexer(ast.NodeVisitor):
             self.fn_stack[-1].calls.add(name)
         # jax.jit(f) / pallas_call(body) / shard_map(body): f is an entry
         if d in ("jax.jit", "jit") or (d and (
-                d.split(".")[-1] in ("pallas_call", "shard_map",
-                                     "shard_map_compat"))):
+                d.split(".")[-1] in ("pallas_call", "shard_map"))):
             for arg in node.args[:1]:
                 if isinstance(arg, ast.Name):
                     fi = self.mod.funcs.get(arg.id)

@@ -171,6 +171,7 @@ class RingState:
         self.upload_bytes = 0
         self.full_uploads = 0
         self.delta_uploads = 0
+        self.last_lookup_kernel: Optional[str] = None   # "bucketed" | "flat"
         # ownership-diff log: (active_version, arcs|None) per mutation
         # batch that moved the active view; None marks an unbounded batch.
         # Recording is opt-in (track_owner_diffs / first owner_diff call)
@@ -798,6 +799,7 @@ class RingState:
                 ohi, olo = ring_lookup_bucketed(khi, klo, *dev,
                                                 use_pallas=use_pallas,
                                                 interpret=interpret)
+                self.last_lookup_kernel = "bucketed"
                 return (np.asarray(ohi).astype(np.uint64) << _WORD) \
                     | np.asarray(olo).astype(np.uint64)
         from repro.kernels.ring_lookup.ops import ring_lookup64
@@ -805,6 +807,7 @@ class RingState:
         idx = np.asarray(ring_lookup64(khi, klo, thi, tlo, n,
                                        use_pallas=use_pallas,
                                        interpret=interpret))
+        self.last_lookup_kernel = "flat"
         return act[idx]
 
     def lookup_keys(self, keys: Sequence[str], *, namespace: str = "") -> np.ndarray:

@@ -71,7 +71,8 @@ def pack_array(arr: np.ndarray) -> bytes:
     ever sees opaque ``bytes`` (like any DHT), so shape/dtype must ride
     inside the value."""
     arr = np.ascontiguousarray(arr)
-    dt = arr.dtype.str.encode()
+    # ml_dtypes types (bfloat16) have an opaque void ``.str``: name them
+    dt = (arr.dtype.name if arr.dtype.kind == "V" else arr.dtype.str).encode()
     head = _MAGIC + struct.pack("<BB", len(dt), arr.ndim) + dt \
         + struct.pack(f"<{arr.ndim}q", *arr.shape)
     return head + arr.tobytes()
@@ -82,7 +83,12 @@ def unpack_array(data: bytes) -> np.ndarray:
         raise ValueError("not a packed array block")
     dtl, ndim = struct.unpack_from("<BB", data, 4)
     off = 6
-    dt = np.dtype(data[off:off + dtl].decode())
+    name = data[off:off + dtl].decode()
+    try:
+        dt = np.dtype(name)
+    except TypeError:                 # bfloat16 & co. register on import
+        import ml_dtypes  # noqa: F401
+        dt = np.dtype(name)
     off += dtl
     shape = struct.unpack_from(f"<{ndim}q", data, off)
     off += 8 * ndim

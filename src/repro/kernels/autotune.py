@@ -9,10 +9,10 @@ A small search-and-cache layer over the five kernels' block/tile sizes
     so one search covers a whole shape class (a churn-driven q=1000 and
     q=1024 lookup share an entry) and the cache stays small.
   * **Persistence** — winners live in a JSON cache file
-    (``$REPRO_AUTOTUNE_CACHE`` or ``~/.cache/repro/autotune.json``); a
-    searched entry is reused by every later process on the same backend.
-    A corrupt or unreadable cache file degrades to the defaults — it can
-    never take the kernels down.
+    (``$REPRO_AUTOTUNE_CACHE`` or ``.autotune/autotune.json`` in the
+    checkout, which git ignores); a searched entry is reused by every
+    later process on the same backend.  A corrupt or unreadable cache
+    file degrades to the defaults — it can never take the kernels down.
   * **Interpret-mode fallback** — on interpret-only backends (CPU tests,
     CI) there is nothing to tune: :func:`tiles_for` returns the
     hand-picked defaults immediately, with no file I/O, and provenance
@@ -30,6 +30,7 @@ import json
 import os
 import tempfile
 import time
+from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple
 
 from .backend import default_interpret
@@ -68,16 +69,17 @@ KERNELS = tuple(DEFAULTS)
 # process-level record of how tiles were resolved (for provenance)
 _resolutions: set = set()
 
+# <checkout>/.autotune (this file is <checkout>/src/repro/kernels/autotune.py)
+_DEFAULT_CACHE = Path(__file__).resolve().parents[3] / ".autotune" \
+    / "autotune.json"
+
 
 def _is_interpret() -> bool:  # indirection so tests can monkeypatch
     return default_interpret()
 
 
 def cache_path() -> str:
-    return os.environ.get(
-        CACHE_ENV,
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "autotune.json"))
+    return os.environ.get(CACHE_ENV, str(_DEFAULT_CACHE))
 
 
 def _backend_key() -> str:
@@ -300,7 +302,9 @@ def autotune_kernel(kernel: str, dims: Dict[str, int], *,
     """Search the candidate tiles for one (kernel, shape bucket) and
     persist the winner.  A cache hit returns WITHOUT re-searching unless
     ``force``; interpret-only backends return the defaults untouched (no
-    search is meaningful against the interpreter)."""
+    search is meaningful against the interpreter).  Raises when the
+    compiler refuses every candidate: the kernel cannot run here at all,
+    and returning the defaults would hide that."""
     if kernel not in DEFAULTS:
         raise KeyError(f"unknown kernel {kernel!r}")
     if _is_interpret():
@@ -317,14 +321,16 @@ def autotune_kernel(kernel: str, dims: Dict[str, int], *,
     cands = [c for c in CANDIDATES[kernel] if _tiles_valid(kernel, c, dims)] \
         or [dict(DEFAULTS[kernel])]
     results: List[Tuple[float, Dict[str, int]]] = []
+    errors: List[str] = []
     for cand in cands:
         try:
             results.append((float(bench(cand)), cand))
-        except Exception:       # a tile the backend rejects is just a loss
-            continue
+        except Exception as e:  # a tile the backend rejects is just a loss
+            errors.append(f"{cand}: {type(e).__name__}: {e}"[:300])
     if not results:
-        _resolutions.add("defaults")
-        return dict(DEFAULTS[kernel])
+        raise RuntimeError(
+            f"{kernel}: the backend refused every candidate tile for "
+            f"{dims}:\n" + "\n".join(errors))
     best_s, best = min(results, key=lambda r: r[0])
     import jax
 

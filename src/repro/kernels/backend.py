@@ -61,17 +61,24 @@ def provenance(interpret: Optional[bool] = None) -> dict:
 
 
 def _device_memory_bytes() -> Optional[int]:
-    """Fast-memory capacity of device 0 via ``memory_stats()``, or None
-    when the backend doesn't report it (CPU, some plugin backends)."""
+    """Memory capacity of device 0 via ``memory_stats()``, or None when
+    a non-TPU backend does not report it (the CPU reports nothing).  A
+    TPU always reports its memory, so a failure there raises instead of
+    reading as "unknown"."""
     import jax
 
+    dev = jax.devices()[0]
+    on_tpu = getattr(dev, "platform", None) == "tpu"
     try:
-        stats = jax.devices()[0].memory_stats()
+        stats = dev.memory_stats()
     except Exception:
+        if on_tpu:
+            raise
         return None
-    if not stats:
-        return None
+    stats = stats or {}
     limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if not limit and on_tpu:
+        raise RuntimeError(f"TPU {dev} reported no memory limit: {stats}")
     return int(limit) if limit else None
 
 
@@ -79,19 +86,17 @@ def _device_memory_bytes() -> Optional[int]:
 def bucket_budget_bytes() -> int:
     """Upper bound on the bucketized ring-lookup table (DESIGN.md §7).
 
-    The bucketized kernel gathers per-query rows from a table resident
-    on the accelerator, so its footprint must respect the device's fast
-    memory: on compiled backends the matrix competes with the query
-    blocks and outputs for on-chip memory, while interpreted backends
-    (CPU tests, CI) only burn host RAM.  RingState stops escalating the
-    directory — and falls back to the flat-scan kernel — once the matrix
-    would outgrow this budget.
+    RingState stops escalating the directory — and falls back to the
+    flat-scan kernel — once the (B, BW) matrix would outgrow this
+    budget.  Interpreted backends (CPU tests, CI) only burn host RAM.
 
-    The compiled-path constant (8 MB, sized for a ~16 MiB-VMEM TPU core)
-    is validated against the device's reported memory when
-    ``memory_stats()`` is available: a small accelerator caps the budget
-    at 1/16 of its actual capacity instead of trusting a constant that
-    could overflow it.
+    The compiled-path constant (8 MB) was sized when the kernel held
+    the whole matrix in one VMEM block (~16 MiB per TPU core).  The
+    kernel now gathers each query's row in device memory (HBM) and
+    streams only row blocks through VMEM, so the constant is stricter
+    than the kernel needs: at n = 10^6 it forces the flat scan.  It is
+    validated against the device's reported memory: a small
+    accelerator caps the budget at 1/16 of its actual capacity.
     """
     if default_interpret():
         return 256 << 20

@@ -58,8 +58,10 @@ def _h2(a, b):
 
 
 def _u01(xp, h):
-    """uint32 hash -> float32 uniform in (0, 1): 24 high bits + half-ulp."""
-    return ((h >> 8).astype(xp.float32) + xp.float32(0.5)) \
+    """uint32 hash -> float32 uniform in (0, 1): 24 high bits + half-ulp.
+    The 24-bit value goes through int32 (exact: it is below 2^24), since
+    the TPU compiler has no uint32 -> float32 cast."""
+    return ((h >> 8).astype(xp.int32).astype(xp.float32) + xp.float32(0.5)) \
         * xp.float32(1.0 / (1 << 24))
 
 

@@ -188,30 +188,34 @@ def ring_lookup64_pallas(keys_hi: jax.Array, keys_lo: jax.Array,
 BW = 128           # bucket row width (one VPU lane row of uint32)
 
 
-def _ring_lookup_bucketed_kernel(qhi_ref, qlo_ref, bhi_ref, blo_ref,
-                                 occ_ref, ohi_ref, olo_ref, *, shift: int):
-    """Per query block: one row gather + one (BQ, BW) compare-and-count.
+def _ring_lookup_bucketed_kernel(qhi_ref, qlo_ref, rhi_ref, rlo_ref,
+                                 occ_ref, ohi_ref, olo_ref):
+    """Per query block: one (BQ, BW) compare-and-count over each query's
+    own bucket row, then a one-hot select of the counted slot.
 
-    The row gather is the paged-attention access pattern (per-query row
-    indices into a table resident outside the block): in interpret mode
-    it is a numpy take; on TPU it lowers to a VMEM gather, so the
-    dispatch layer only selects this kernel while the bucket matrix fits
-    the device budget (repro.kernels.backend.bucket_budget_bytes).
-    """
-    qhi = qhi_ref[...]                               # (BQ,) uint32
+    The rows arrive already gathered (one per query, by the wrapper), so
+    the body is pure VPU lane work: no in-kernel gather, no
+    take_along_axis.  Queries and occupancies are (BQ, 1) columns that
+    broadcast along the row's lanes."""
+    qhi = qhi_ref[...]                               # (BQ, 1) uint32
     qlo = qlo_ref[...]
-    b = jax.lax.shift_right_logical(
-        qhi, jnp.uint32(shift)).astype(jnp.int32) if shift < 32 \
-        else jnp.zeros_like(qhi, jnp.int32)
-    rhi = jnp.take(bhi_ref[...], b, axis=0)          # (BQ, BW)
-    rlo = jnp.take(blo_ref[...], b, axis=0)
-    occ = jnp.take(occ_ref[...], b)                  # (BQ,)
+    rhi = rhi_ref[...]                               # (BQ, BW) uint32
+    rlo = rlo_ref[...]
     j = jax.lax.broadcasted_iota(jnp.int32, rhi.shape, 1)
-    lt = (rhi < qhi[:, None]) | (
-        (rhi == qhi[:, None]) & (rlo < qlo[:, None]))
-    cnt = jnp.sum((lt & (j < occ[:, None])).astype(jnp.int32), axis=1)
-    ohi_ref[...] = jnp.take_along_axis(rhi, cnt[:, None], axis=1)[:, 0]
-    olo_ref[...] = jnp.take_along_axis(rlo, cnt[:, None], axis=1)[:, 0]
+    lt = (rhi < qhi) | ((rhi == qhi) & (rlo < qlo))
+    cnt = jnp.sum((lt & (j < occ_ref[...])).astype(jnp.int32), axis=1,
+                  keepdims=True)                     # (BQ, 1)
+    pick = j == cnt
+
+    def select(rows):
+        # exactly one lane survives, so an int32 sum is an exact select
+        words = jax.lax.bitcast_convert_type(
+            jnp.where(pick, rows, jnp.uint32(0)), jnp.int32)
+        return jax.lax.bitcast_convert_type(
+            jnp.sum(words, axis=1, keepdims=True), jnp.uint32)
+
+    ohi_ref[...] = select(rhi)
+    olo_ref[...] = select(rlo)
 
 
 def ring_lookup_bucketed_pallas(keys_hi: jax.Array, keys_lo: jax.Array,
@@ -240,24 +244,25 @@ def ring_lookup_bucketed_pallas(keys_hi: jax.Array, keys_lo: jax.Array,
     qp = (q + BQ - 1) // BQ * BQ
     keys_hi = jnp.pad(keys_hi, (0, qp - q))
     keys_lo = jnp.pad(keys_lo, (0, qp - q))
+    # top-R radix of each key; R = 0 (one bucket) would shift by 32
+    b = jax.lax.shift_right_logical(keys_hi, jnp.uint32(32 - r)).astype(
+        jnp.int32) if r else jnp.zeros((qp,), jnp.int32)
+    # the row gather runs in XLA over the matrix in device memory; the
+    # kernel then streams one (BQ, BW) row block per query block
+    rows_hi = jnp.take(bkt_hi, b, axis=0)            # (qp, BW)
+    rows_lo = jnp.take(bkt_lo, b, axis=0)
+    occ_q = jnp.take(occ, b)[:, None]                # (qp, 1)
+    col = pl.BlockSpec((BQ, 1), lambda qi: (qi, 0))
+    row = pl.BlockSpec((BQ, BW), lambda qi: (qi, 0))
     out_hi, out_lo = pl.pallas_call(
-        functools.partial(_ring_lookup_bucketed_kernel, shift=32 - r),
+        _ring_lookup_bucketed_kernel,
         grid=(qp // BQ,),
-        in_specs=[
-            pl.BlockSpec((BQ,), lambda qi: (qi,)),
-            pl.BlockSpec((BQ,), lambda qi: (qi,)),
-            pl.BlockSpec((nb, BW), lambda qi: (0, 0)),
-            pl.BlockSpec((nb, BW), lambda qi: (0, 0)),
-            pl.BlockSpec((nb,), lambda qi: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BQ,), lambda qi: (qi,)),
-            pl.BlockSpec((BQ,), lambda qi: (qi,)),
-        ],
+        in_specs=[col, col, row, row, col],
+        out_specs=[col, col],
         out_shape=[
-            jax.ShapeDtypeStruct((qp,), jnp.uint32),
-            jax.ShapeDtypeStruct((qp,), jnp.uint32),
+            jax.ShapeDtypeStruct((qp, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((qp, 1), jnp.uint32),
         ],
         interpret=interpret,
-    )(keys_hi, keys_lo, bkt_hi, bkt_lo, occ)
-    return out_hi[:q], out_lo[:q]
+    )(keys_hi[:, None], keys_lo[:, None], rows_hi, rows_lo, occ_q)
+    return out_hi[:q, 0], out_lo[:q, 0]

@@ -14,17 +14,14 @@ import numpy as np
 from jax.sharding import Mesh
 
 
-def _auto_kw(n: int) -> dict:
-    """axis_types=Auto on jax versions that have it, {} otherwise (jax
-    0.4.x meshes are implicitly auto — passing the kwarg would crash)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {} if at is None else {"axis_types": (at.Auto,) * n}
+def _auto(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model_axis: int = 1) -> Mesh:
@@ -43,7 +40,7 @@ def make_host_mesh(model_axis: int = 1) -> Mesh:
             f"--xla_force_host_platform_device_count=<multiple of "
             f"{model_axis}>)")
     return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
-                         **_auto_kw(2))
+                         axis_types=_auto(2))
 
 
 def replica_groups(mesh: Union[Mesh, Sequence, None], tp: int,
@@ -68,7 +65,7 @@ def replica_groups(mesh: Union[Mesh, Sequence, None], tp: int,
             f"tp={tp} must be >= 1 and divide the {n} pooled device(s) "
             f"exactly — a partial group cannot hold a full weight shard "
             f"set")
-    return [Mesh(np.array(devices[i:i + tp]), (axis,), **_auto_kw(1))
+    return [Mesh(np.array(devices[i:i + tp]), (axis,), axis_types=_auto(1))
             for i in range(0, n, tp)]
 
 

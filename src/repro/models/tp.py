@@ -35,7 +35,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.sharding import specs as sh
-from repro.sharding.collectives import shard_map_compat
 from . import transformer
 from .model import Model
 
@@ -190,8 +189,9 @@ class TPReplicaGroup:
             def inner(*args):
                 with sh.tp_context(axis):
                     return f(*args)
-            return jax.jit(shard_map_compat(inner, mesh, in_specs,
-                                            out_specs))
+            return jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                                         out_specs=out_specs,
+                                         check_vma=False))
 
         prefill = wrap(lambda p, b, c: lm.prefill(p, b, c),
                        (pP, {"tokens": rep(2)}, cP), (logit1, cP))

@@ -49,7 +49,9 @@ def _stack(cfg: ModelConfig, rng, abstract: bool, n_layers: int) -> Params:
         return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct((n_layers,) + s.shape, s.dtype), one)
     rngs = jax.random.split(rng, n_layers)
-    return jax.vmap(lambda r: _layer_params(cfg, r, False))(rngs)
+    # one layer at a time: a layer's float32 draws are the only float32
+    # temporaries, never a float32 copy of the whole stack
+    return jax.lax.map(lambda r: _layer_params(cfg, r, False), rngs)
 
 
 def init_params(cfg: ModelConfig, rng=None, abstract: bool = False) -> Params:

@@ -203,6 +203,9 @@ class ServeCluster:
         self.cross_region_admits = 0
         self.cross_region_migrations = 0
         self.stranded = 0                  # handoff attempts deferred on
+        # overlapped re-prefills that failed mid-chunk (re-stranded, so
+        # they retry; the count keeps the failure visible)
+        self.prefill_failures = 0
         # overlapped migration re-prefills in flight: sid -> target node
         self._pending_homes: Dict[str, Dict] = {}
         self._retry: Set[str] = set()      # sids needing an off-event re-home
@@ -689,6 +692,7 @@ class ServeCluster:
         for sid in rep.failed_prefills:
             self._pending_homes.pop(sid, None)
             self._retry.add(sid)
+            self.prefill_failures += 1
         rep.failed_prefills.clear()
 
     def _service_pending(self) -> None:
@@ -948,6 +952,7 @@ class ServeCluster:
             "replicas": len(self.replicas),
             "migrated": self.migrated_sessions,
             "stranded": self.stranded,
+            "prefill_failures": self.prefill_failures,
             "proxied": sum(self.proxied.values()),
             "route_uploads": self.state.upload_count,
             "route_upload_bytes": self.state.upload_bytes,

@@ -26,29 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax moved shard_map out of experimental and renamed check_rep ->
-# check_vma; support both so the EDRA collectives run on any jax >= 0.4.3x.
-if hasattr(jax, "shard_map"):
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable jax.shard_map with replication checking off by
-    default (the EDRA schedules intentionally produce per-device values)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
-
-
-def _axis_size(axis_name: str) -> int:
-    """Mapped-axis size as a Python int on any jax version: psum of the
-    literal 1 is constant-folded to the axis size (no communication)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
 
 def _rounds(n: int) -> int:
     r = int(math.log2(n))
@@ -63,7 +40,7 @@ def edra_allgather(x: jax.Array, axis_name: str) -> jax.Array:
     Inside shard_map: x is the local block; returns (n, *x.shape) stacked
     in ring order (block j = peer j's shard).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rho = _rounds(n)
     idx = jax.lax.axis_index(axis_name)
     buf = x[None]                                   # blocks [i]
@@ -84,7 +61,7 @@ def edra_broadcast(x: jax.Array, axis_name: str, source: int = 0) -> jax.Array:
     log2(n) rounds; peers outside the frontier forward zeros that are
     overwritten on receipt (static schedule, exactly-once per Theorem 1).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rho = _rounds(n)
     idx = jax.lax.axis_index(axis_name)
     off = (idx - source) % n                        # offset from reporter
@@ -102,7 +79,7 @@ def edra_broadcast(x: jax.Array, axis_name: str, source: int = 0) -> jax.Array:
 def edra_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     """DP all-reduce: native reduce-scatter (the reduction half has no
     analogue in the paper) + EDRA-tree all-gather for dissemination."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % n
     if pad:
@@ -122,11 +99,12 @@ def make_edra_allreduce(mesh: Mesh, axis_name: str = "data"):
 
     def tree_allreduce(tree):
         def one(g):
-            fn = shard_map_compat(
+            fn = jax.shard_map(
                 partial(edra_allreduce, axis_name=axis_name),
-                mesh,
+                mesh=mesh,
                 in_specs=P(*(None for _ in g.shape)),
                 out_specs=P(*(None for _ in g.shape)),
+                check_vma=False,
             )
             return fn(g)
         return jax.tree.map(one, tree)

@@ -167,3 +167,52 @@ def test_budget_reads_memory_stats_from_device(budget_caches, monkeypatch):
 
     monkeypatch.setattr(jax, "devices", lambda *a: [TinyDev()])
     assert backend.bucket_budget_bytes() == 3 << 20
+
+
+def test_search_raises_when_every_candidate_is_refused(compiled_cache):
+    """A sweep in which the compiler refuses every tile means the kernel
+    cannot run on this backend: that must raise, not read as defaults."""
+    def refuse(tiles):
+        raise ValueError(f"refused {tiles}")
+
+    with pytest.raises(RuntimeError, match="refused every candidate"):
+        autotune.autotune_kernel("decode_attention", {"s": 2048},
+                                 bench=refuse)
+    assert not compiled_cache.exists()
+
+
+def test_default_cache_lives_in_the_checkout(monkeypatch):
+    from pathlib import Path
+
+    monkeypatch.delenv(autotune.CACHE_ENV, raising=False)
+    root = Path(autotune.__file__).resolve().parents[3]
+    assert Path(autotune.cache_path()) == root / ".autotune" / "autotune.json"
+    monkeypatch.setenv(autotune.CACHE_ENV, "/elsewhere/at.json")
+    assert autotune.cache_path() == "/elsewhere/at.json"
+
+
+@pytest.mark.parametrize("platform, stats, expect", [
+    ("cpu", RuntimeError("no stats"), None),
+    ("cpu", None, None),
+    ("tpu", {"bytes_limit": 16 << 30}, 16 << 30),
+    ("tpu", RuntimeError("no stats"), RuntimeError),
+    ("tpu", {}, RuntimeError),
+])
+def test_device_memory_never_hides_a_tpu(monkeypatch, platform, stats, expect):
+    """Off a TPU, missing memory stats read as unknown; on a TPU they are
+    an error, not a silent fallback to the 8 MB constant."""
+    import jax
+
+    class Dev:
+        def memory_stats(self):
+            if isinstance(stats, Exception):
+                raise stats
+            return stats
+
+    Dev.platform = platform
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError):
+            backend._device_memory_bytes()
+    else:
+        assert backend._device_memory_bytes() == expect

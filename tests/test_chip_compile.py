@@ -1,0 +1,101 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+
+Nothing runs: each kernel is lowered and compiled ahead of time against
+a described (not attached) v5e, so a kernel the chip's compiler refuses
+fails here instead of on the chip.  The topology is described inside a
+module fixture — never at import — because only one process at a time
+may load the TPU compiler library.  JAX's persistent compilation cache
+is off around these compiles: an entry compiled for a described chip
+cannot be read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+
+QWEN = get_config("qwen2.5-3b")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_ring_lookup64_compiles(spec):
+    from repro.kernels.ring_lookup.kernel import ring_lookup64_pallas
+    u32 = jnp.uint32
+    _compile(lambda a, b, c, d, n: ring_lookup64_pallas(
+        a, b, c, d, n, interpret=False),
+        spec((4096,), u32), spec((4096,), u32), spec((1 << 20,), u32),
+        spec((1 << 20,), u32), spec((1,), jnp.int32))
+
+
+def test_ring_lookup_bucketed_compiles(spec):
+    from repro.kernels.ring_lookup.kernel import (BW,
+                                                  ring_lookup_bucketed_pallas)
+    u32, nb = jnp.uint32, 8192
+    _compile(lambda a, b, c, d, o: ring_lookup_bucketed_pallas(
+        a, b, c, d, o, interpret=False),
+        spec((4096,), u32), spec((4096,), u32), spec((nb, BW), u32),
+        spec((nb, BW), u32), spec((nb,), jnp.int32))
+
+
+def test_edra_tree_compiles(spec):
+    from repro.kernels.edra_tree.kernel import edra_tree_pallas
+    p, u32 = 1 << 20, jnp.uint32
+    _compile(lambda o, n, r, t, k: edra_tree_pallas(
+        o, n, r, t, k, levels=20, theta=1.0, delta_avg=0.0625,
+        interpret=False),
+        spec((p,), u32), spec((p,), u32), spec((p,), u32),
+        spec((p,), jnp.float32), spec((p,), u32))
+
+
+def test_decode_attention_compiles(spec):
+    from repro.kernels.decode_attention.kernel import decode_attention_pallas
+    b, s, hd = 8, 2048, QWEN.resolved_head_dim
+    h, hkv, bf16 = QWEN.num_heads, QWEN.num_kv_heads, jnp.bfloat16
+    _compile(lambda q, k, v, n: decode_attention_pallas(
+        q, k, v, n, interpret=False),
+        spec((b, h, hd), bf16), spec((b, s, hkv, hd), bf16),
+        spec((b, s, hkv, hd), bf16), spec((b,), jnp.int32))
+
+
+def test_flash_attention_compiles(spec):
+    from repro.kernels.flash_attention.kernel import flash_attention_pallas
+    s, hd = 2048, QWEN.resolved_head_dim
+    h, hkv, bf16 = QWEN.num_heads, QWEN.num_kv_heads, jnp.bfloat16
+    _compile(lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, interpret=False),
+        spec((1, s, h, hd), bf16), spec((1, s, hkv, hd), bf16),
+        spec((1, s, hkv, hd), bf16))

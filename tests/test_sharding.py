@@ -104,22 +104,24 @@ import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.sharding.collectives import (edra_allgather, edra_broadcast,
-                                        edra_allreduce, shard_map_compat)
+                                        edra_allreduce)
 mesh = jax.make_mesh((8,), ("d",))
 x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
-ag = shard_map_compat(partial(edra_allgather, axis_name="d"), mesh,
-                      in_specs=P("d", None), out_specs=P("d", None, None))
+ag = jax.shard_map(partial(edra_allgather, axis_name="d"), mesh=mesh,
+                   in_specs=P("d", None), out_specs=P("d", None, None),
+                   check_vma=False)
 got = np.asarray(ag(x)).reshape(8, 8, 4)
 for i in range(8):
     assert (got[i].squeeze() == np.asarray(x)).all()
 for src in (0, 3, 7):
-    bc = shard_map_compat(partial(edra_broadcast, axis_name="d", source=src),
-                          mesh, in_specs=P("d", None),
-                          out_specs=P("d", None))
+    bc = jax.shard_map(partial(edra_broadcast, axis_name="d", source=src),
+                       mesh=mesh, in_specs=P("d", None),
+                       out_specs=P("d", None), check_vma=False)
     got = np.asarray(bc(x))
     assert (got == np.tile(np.asarray(x)[src], (8, 1))).all()
-ar = shard_map_compat(partial(edra_allreduce, axis_name="d"), mesh,
-                      in_specs=P(None, None), out_specs=P(None, None))
+ar = jax.shard_map(partial(edra_allreduce, axis_name="d"), mesh=mesh,
+                   in_specs=P(None, None), out_specs=P(None, None),
+                   check_vma=False)
 y = jnp.arange(5 * 3, dtype=jnp.float32).reshape(5, 3)
 assert np.allclose(np.asarray(ar(y)), np.asarray(y) * 8)
 print("COLLECTIVES_OK")
@@ -141,7 +143,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro.sharding.collectives import edra_allreduce, shard_map_compat
+from repro.sharding.collectives import edra_allreduce
 
 # data-parallel gradient sync via the paper's dissemination tree:
 # per-shard grads -> reduce-scatter + EDRA-tree all-gather == psum
@@ -158,10 +160,10 @@ def local_grad(w_, x_, y_):
     g = jax.grad(lambda wt: jnp.mean((x_ @ wt - y_) ** 2))(w_)
     return edra_allreduce(g, "data") / 8.0
 
-sm = shard_map_compat(local_grad, mesh,
-                      in_specs=(P(None, None), P("data", None),
-                                P("data", None)),
-                      out_specs=P(None, None))
+sm = jax.shard_map(local_grad, mesh=mesh,
+                   in_specs=(P(None, None), P("data", None),
+                             P("data", None)),
+                   out_specs=P(None, None), check_vma=False)
 step = jax.jit(sm)
 g_edra = np.asarray(step(w, x, y))
 g_ref = np.asarray(jax.grad(lambda wt: jnp.mean((x @ wt - y) ** 2))(w))
