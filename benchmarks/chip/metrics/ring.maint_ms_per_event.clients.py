@@ -1,0 +1,5 @@
+"""``ring.maint_ms_per_event`` as the clients cell reads it, where it moves
+``lookups_per_s.clients``."""
+from chipbench.common import load_reader
+
+read = load_reader("ring.maint_ms_per_event")

@@ -1,0 +1,5 @@
+"""``ring_lookup_roofline`` as the clients cell reads it, where it moves
+``lookups_per_s.clients``."""
+from chipbench.common import load_reader
+
+read = load_reader("ring_lookup_roofline")
