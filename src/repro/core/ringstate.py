@@ -53,6 +53,8 @@ from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.analysis import spans
+
 _MIN_CAPACITY = 64
 _MIN_DEVICE_CAPACITY = 2048   # one kernel table tile (kernel.BT)
 _WORD = np.uint64(32)
@@ -391,6 +393,10 @@ class RingState:
         in one batch nets out).  Returns the number of table slots that
         changed; bumps ``version`` iff non-zero.
         """
+        with spans.span(spans.RING_APPLY_EVENTS, events=len(events)):
+            return self._apply_events(events)
+
+    def _apply_events(self, events: Sequence) -> int:
         last: dict = {}
         for ev in events:
             last[int(ev.subject_id)] = ev.kind
@@ -695,23 +701,25 @@ class RingState:
         import jax.numpy as jnp  # lazy: keep pure-python users jax-free
 
         nb = 1 << self._bkt_bits
-        if self._bkt_dev is None or self._bkt_dev_bits != self._bkt_bits:
-            hi, lo = self._build_rows(np.arange(nb))
-            self._bkt_dev = (jnp.asarray(hi), jnp.asarray(lo),
-                             jnp.asarray(self._bkt_occ))
-            self._bkt_dev_bits = self._bkt_bits
-            self.full_uploads += 1
-            self.upload_bytes += nb * (_BUCKET_ROW * 8 + 4)
-        else:
-            rows = np.nonzero(self._bkt_dirty)[0]
+        full = self._bkt_dev is None or self._bkt_dev_bits != self._bkt_bits
+        rows = np.arange(nb) if full else np.nonzero(self._bkt_dirty)[0]
+        nbytes = int(rows.size) * (_BUCKET_ROW * 8 + 4)
+        with spans.span(spans.RING_TABLE_UPLOAD, bytes=nbytes):
             hi, lo = self._build_rows(rows)
-            bhi, blo, occ = self._bkt_dev
-            at = jnp.asarray(rows.astype(np.int32))
-            self._bkt_dev = (bhi.at[at].set(jnp.asarray(hi)),
-                             blo.at[at].set(jnp.asarray(lo)),
-                             occ.at[at].set(jnp.asarray(self._bkt_occ[rows])))
-            self.delta_uploads += 1
-            self.upload_bytes += int(rows.size) * (_BUCKET_ROW * 8 + 4)
+            if full:
+                self._bkt_dev = (jnp.asarray(hi), jnp.asarray(lo),
+                                 jnp.asarray(self._bkt_occ))
+                self._bkt_dev_bits = self._bkt_bits
+                self.full_uploads += 1
+            else:
+                bhi, blo, occ = self._bkt_dev
+                at = jnp.asarray(rows.astype(np.int32))
+                self._bkt_dev = (
+                    bhi.at[at].set(jnp.asarray(hi)),
+                    blo.at[at].set(jnp.asarray(lo)),
+                    occ.at[at].set(jnp.asarray(self._bkt_occ[rows])))
+                self.delta_uploads += 1
+        self.upload_bytes += nbytes
         self.upload_count += 1
         self._bkt_dirty[:] = False
         return self._bkt_dev
@@ -756,12 +764,13 @@ class RingState:
         cap = max(self._dev_capacity, _MIN_DEVICE_CAPACITY)
         while cap < n:
             cap *= 2
-        hi = np.zeros(cap, np.uint32)
-        lo = np.zeros(cap, np.uint32)
-        hi[:n] = (act >> _WORD).astype(np.uint32)
-        lo[:n] = (act & _LO_MASK).astype(np.uint32)
-        self._dev = (jnp.asarray(hi), jnp.asarray(lo),
-                     jnp.asarray([n], jnp.int32))
+        with spans.span(spans.RING_TABLE_UPLOAD, bytes=cap * 8 + 4):
+            hi = np.zeros(cap, np.uint32)
+            lo = np.zeros(cap, np.uint32)
+            hi[:n] = (act >> _WORD).astype(np.uint32)
+            lo[:n] = (act & _LO_MASK).astype(np.uint32)
+            self._dev = (jnp.asarray(hi), jnp.asarray(lo),
+                         jnp.asarray([n], jnp.int32))
         self._dev_capacity = cap
         self._dev_version = self.active_version
         self.upload_count += 1
@@ -782,33 +791,45 @@ class RingState:
         preference (True still falls back when the index is invalid);
         ``interpret=None`` autodetects the backend: compiled on real
         TPUs, interpreter mode elsewhere."""
+        keys = np.asarray(keys, np.uint64)
+        with spans.span(spans.RING_LOOKUP, keys=keys.size) as sp:
+            owners = self._lookup(keys, use_pallas, interpret, use_buckets)
+            spans.note(sp, kernel=self.last_lookup_kernel)
+        return owners
+
+    def _lookup(self, keys, use_pallas, interpret, use_buckets):
         import jax.numpy as jnp
 
         act = self.active_ids()
         if not act.size:
             raise LookupError("empty routing table")
-        keys = np.asarray(keys, np.uint64)
-        khi = jnp.asarray((keys >> _WORD).astype(np.uint32))
-        klo = jnp.asarray((keys & _LO_MASK).astype(np.uint32))
+        with spans.span(spans.RING_UPLOAD_KEYS):
+            khi = jnp.asarray((keys >> _WORD).astype(np.uint32))
+            klo = jnp.asarray((keys & _LO_MASK).astype(np.uint32))
         if use_buckets is None:
             use_buckets = act.size >= _BUCKET_MIN_N
         if use_buckets:
             dev = self.device_bucket_table()
             if dev is not None:
                 from repro.kernels.ring_lookup.ops import ring_lookup_bucketed
-                ohi, olo = ring_lookup_bucketed(khi, klo, *dev,
-                                                use_pallas=use_pallas,
-                                                interpret=interpret)
+                with spans.span(spans.RING_KERNEL):
+                    ohi, olo = ring_lookup_bucketed(khi, klo, *dev,
+                                                    use_pallas=use_pallas,
+                                                    interpret=interpret)
+                    ohi, olo = np.asarray(ohi), np.asarray(olo)
                 self.last_lookup_kernel = "bucketed"
-                return (np.asarray(ohi).astype(np.uint64) << _WORD) \
-                    | np.asarray(olo).astype(np.uint64)
+                with spans.span(spans.RING_OWNERS):
+                    return (ohi.astype(np.uint64) << _WORD) \
+                        | olo.astype(np.uint64)
         from repro.kernels.ring_lookup.ops import ring_lookup64
         thi, tlo, n = self.device_table()
-        idx = np.asarray(ring_lookup64(khi, klo, thi, tlo, n,
-                                       use_pallas=use_pallas,
-                                       interpret=interpret))
+        with spans.span(spans.RING_KERNEL):
+            idx = np.asarray(ring_lookup64(khi, klo, thi, tlo, n,
+                                           use_pallas=use_pallas,
+                                           interpret=interpret))
         self.last_lookup_kernel = "flat"
-        return act[idx]
+        with spans.span(spans.RING_OWNERS):
+            return act[idx]
 
     def lookup_keys(self, keys: Sequence[str], *, namespace: str = "") -> np.ndarray:
         """Hash string keys onto the ring and resolve owners on-device."""

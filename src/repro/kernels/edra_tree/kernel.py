@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from .ref import tree_math
 
 BP = 2048          # pairs per program (16 sublanes x 128 lanes of uint32)
+NAME = "edra_tree"   # the pallas_call's name in jaxprs and traces
 
 
 def _edra_tree_kernel(off_ref, n_ref, rep_ref, t0_ref, key_ref,
@@ -88,5 +89,6 @@ def edra_tree_pallas(offset: jax.Array, n: jax.Array, reporter: jax.Array,
             jax.ShapeDtypeStruct((pp,), jnp.int32),
         ],
         interpret=interpret,
+        name=NAME,
     )(offset, n, reporter, t_detect, event_key)
     return ack[:p], ttl[:p], depth[:p], parent[:p], sends[:p]

@@ -24,6 +24,9 @@ from jax.experimental import pallas as pl
 
 BQ = 1024          # queries per program (8 sublanes x 128 lanes)
 BT = 2048          # table entries per tile (8 KiB of uint32 in VMEM)
+# the pallas_calls' names in jaxprs and traces
+NAME64 = "ring_lookup64"
+NAME_BUCKETED = "ring_lookup_bucketed"
 
 
 def _tiles(kernel: str, bq, bt=None, **dims):
@@ -156,6 +159,7 @@ def ring_lookup64_pallas(keys_hi: jax.Array, keys_lo: jax.Array,
         out_specs=pl.BlockSpec((BQ,), lambda qi, ti: (qi,)),
         out_shape=jax.ShapeDtypeStruct((qp,), jnp.int32),
         interpret=interpret,
+        name=NAME64,
     )(n.astype(jnp.int32), keys_hi, keys_lo, table_hi, table_lo)
     return (counts[:q] % n[0]).astype(jnp.int32)
 
@@ -264,5 +268,6 @@ def ring_lookup_bucketed_pallas(keys_hi: jax.Array, keys_lo: jax.Array,
             jax.ShapeDtypeStruct((qp, 1), jnp.uint32),
         ],
         interpret=interpret,
+        name=NAME_BUCKETED,
     )(keys_hi[:, None], keys_lo[:, None], rows_hi, rows_lo, occ_q)
     return out_hi[:q, 0], out_lo[:q, 0]

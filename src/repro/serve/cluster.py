@@ -34,7 +34,7 @@ from typing import Any, Dict, List, Optional, Set
 
 import numpy as np
 
-from repro.analysis.metering import metered
+from repro.analysis import spans
 from repro.core.ringstate import _BUCKET_MIN_N
 from repro.dht.data import BlockStore, PrefixCache, pack_array, unpack_array
 from repro.models import Model
@@ -85,27 +85,37 @@ class RequestTrace:
     microseconds) — the measured request-latency plane's serve-side leg
     (DESIGN.md §9):
 
-      * ``route_us``  — owner resolution (replica_set successor walks at
-        submit and on every migration);
+      * ``route_us``  — owner resolution on the host (replica_set
+        successor walks at submit and on every migration); the fused
+        rounds' on-device route is read from the ``route`` scope of a
+        profiler trace instead;
       * ``queue_us``  — capacity probing plus any time the session spent
         stranded waiting for a replica_set slot to free;
       * ``decode_us`` — prefill(s), including migration re-prefills, plus
-        this session's share of every decode round it took a token from;
+        this session's share of every decode round it took a token from
+        (a fused round's wall time, route included);
       * ``handoff_us`` — cache-TRANSFER time on migrations: fetching the
         session's KV blocks from the replica set plus importing them
         into the new replica's cache.  Kept apart from ``decode_us`` so
         a handoff (transfer) and a re-prefill (recompute) migration are
         distinguishable in the report instead of both landing in the
         route/decode buckets.
+
+    ``first_token_ns`` is when the session's first token was pushed, and
+    ``resumed_ns`` when its first token after its latest migration was
+    (0 until then), on the same clock as ``submitted_ns``.
     """
 
     submitted_ns: int = 0
     completed_ns: int = 0
+    first_token_ns: int = 0
+    resumed_ns: int = 0
     queue_us: float = 0.0
     route_us: float = 0.0
     decode_us: float = 0.0
     handoff_us: float = 0.0
     _stranded_ns: int = 0          # transient: set while awaiting re-home
+    _moved: bool = False           # transient: migrated, no token since
 
     @property
     def done(self) -> bool:
@@ -239,7 +249,6 @@ class ServeCluster:
         # fused-route owners that differ from the control plane's record:
         # sessions living on a replica_set spill member or mid-migration
         self.route_divergence = 0
-        self._route_cal_us_per_key: Optional[float] = None
         self.state.track_owner_diffs()     # arm arc logging before events
         self._seen_version = self.state.active_version
         membership.subscribe(self._on_event)
@@ -391,14 +400,20 @@ class ServeCluster:
             self.proxied[via] = self.proxied.get(via, 0) + 1
         if origin is None:
             origin = via
+        with spans.span(spans.SERVE_SUBMIT, session=req.session_id):
+            return self._submit(req, origin)
+
+    def _submit(self, req: Request, origin) -> int:
         t_sub = time.perf_counter_ns()
         key = session_key(req.session_id)
         # host-side policy-ranked replica group (no device dispatch for a
         # single key); admission spills down the ranked group exactly
         # like migration does, so a hot arc fills its group before
         # rejecting — ring-successor order under the default policy
-        group = self.policy.replica_group(self.state, key,
-                                          self.replication, origin=origin)
+        with spans.span(spans.SERVE_ROUTE):
+            group = self.policy.replica_group(self.state, key,
+                                              self.replication,
+                                              origin=origin)
         t_route = time.perf_counter_ns()
         cands = [n for n in group if self._has_capacity(n)]
         owner = cands[0] if cands else None
@@ -420,7 +435,8 @@ class ServeCluster:
                             req.max_new_tokens, owner=owner, origin=origin)
         self._note_region(rec, migration=False)
         t_queue = time.perf_counter_ns()
-        tok = self._replica_for(owner).admit(req)
+        with spans.span(spans.SERVE_ADMIT):
+            tok = self._replica_for(owner).admit(req)
         self._note_warm(owner, rec.prompt)
         t_admit = time.perf_counter_ns()
         self.traces[req.session_id] = RequestTrace(
@@ -506,20 +522,26 @@ class ServeCluster:
             return
         c = self.prefill_chunk
         full = int(rep.lengths[slot]) // c
-        for j in range(rec.exported_chunks, full):
-            # per-shard export: each device of a TP group ships only its
-            # kv_heads slice (one slab for single-device replicas).
-            # Placed AT the session's ring key, not the block-name hash:
-            # the session and its blocks share ONE replica set, so the
-            # migration target the policy picks already holds the
-            # handoff blocks locally — BlockStore.sync() and migration
-            # can no longer re-home them to different replicas
-            for s_i, slab in enumerate(
-                    rep.export_block_shards(rec.session_id, j)):
-                self.blocks.put(self._block_name(rec.session_id, j, s_i),
-                                pack_array(slab), at=rec.key)
-            self.exported_blocks += 1
-        rec.exported_chunks = max(rec.exported_chunks, full)
+        if full <= rec.exported_chunks:
+            return
+        with spans.span(spans.KV_EXPORT, session=rec.session_id,
+                        chunks=full - rec.exported_chunks):
+            for j in range(rec.exported_chunks, full):
+                # per-shard export: each device of a TP group ships only
+                # its kv_heads slice (one slab for single-device
+                # replicas).  Placed AT the session's ring key, not the
+                # block-name hash: the session and its blocks share ONE
+                # replica set, so the migration target the policy picks
+                # already holds the handoff blocks locally —
+                # BlockStore.sync() and migration can no longer re-home
+                # them to different replicas
+                for s_i, slab in enumerate(
+                        rep.export_block_shards(rec.session_id, j)):
+                    self.blocks.put(
+                        self._block_name(rec.session_id, j, s_i),
+                        pack_array(slab), at=rec.key)
+                self.exported_blocks += 1
+        rec.exported_chunks = full
 
     def _fetch_blocks(self, rec: SessionRecord, s: int) -> List[np.ndarray]:
         """The longest contiguous run of the session's stored KV chunks,
@@ -567,9 +589,15 @@ class ServeCluster:
 
     def _push_token(self, rec: SessionRecord, tok: int) -> None:
         rec.generated.append(tok)
+        trace = self.traces.get(rec.session_id)
+        if trace is not None and (not trace.first_token_ns or trace._moved):
+            now = time.perf_counter_ns()
+            trace.first_token_ns = trace.first_token_ns or now
+            if trace._moved:
+                trace.resumed_ns = now
+                trace._moved = False
         if len(rec.generated) >= rec.max_new_tokens:
             rec.done = True
-            trace = self.traces.get(rec.session_id)
             if trace is not None and not trace.done:
                 trace.completed_ns = time.perf_counter_ns()
             rep = self.replicas.get(rec.owner)
@@ -591,40 +619,22 @@ class ServeCluster:
             return None
         return self.state.device_bucket_table()
 
-    @metered
-    def _calibrate_route(self, rep: Replica, route) -> None:
-        """One-time per-key cost of the on-device route, measured by
-        timing the bucketized lookup standalone on this replica's key
-        slab (warm trace, second call timed).  The fused round is ONE
-        dispatch, so this is how the queue/route/decode trace splits
-        survive fusion: the round's wall time is split into a route
-        share (this calibration x keys) and a decode share.
-
-        ``@metered``: the two block_until_ready syncs are the
-        measurement — repro-lint RL003 allowlists this site, and the
-        meter counter lets tests assert it stays out of the round loop
-        (one call per (replica, ring-version), never per round)."""
-        import jax
-        import jax.numpy as jnp
-
-        from repro.kernels.ring_lookup.ops import ring_lookup_bucketed
-        khi = jnp.asarray(rep.key_hi)
-        klo = jnp.asarray(rep.key_lo)
-        jax.block_until_ready(ring_lookup_bucketed(khi, klo, *route))
-        t0 = time.perf_counter_ns()
-        jax.block_until_ready(ring_lookup_bucketed(khi, klo, *route))
-        self._route_cal_us_per_key = \
-            (time.perf_counter_ns() - t0) / 1e3 / max(rep.key_hi.size, 1)
-
     def step(self) -> Dict[str, int]:
         """One continuous-batching round across every replica: advance
         in-flight overlapped prefills by one chunk, then run one decode
         round (fused with the on-device route when enabled)."""
-        if self._seen_version != self.state.active_version:
-            self._migrate_affected()       # retry deferred re-homes
-        self._service_pending()
-        route = self._route_table()
         self._step_seq += 1
+        with spans.step_span(spans.SERVE_STEP, self._step_seq):
+            return self._step()
+
+    def _step(self) -> Dict[str, int]:
+        with spans.span(spans.SERVE_MIGRATE) as sp:
+            moved = 0
+            if self._seen_version != self.state.active_version:
+                moved = self._migrate_affected()   # retry deferred re-homes
+            self._service_pending()
+            spans.note(sp, moved=moved)
+        route = self._route_table()
         duty_turn = self._step_seq % self.prefill_duty == 0
         out: Dict[str, int] = {}
         for node in list(self.replicas):
@@ -633,31 +643,27 @@ class ServeCluster:
             # when the replica has no decode traffic to protect
             if rep.num_pending and (duty_turn or not rep.sessions):
                 t0 = time.perf_counter_ns()
-                completions = rep.advance_prefills()
+                with spans.span(spans.SERVE_PREFILL_ADVANCE, node=node):
+                    completions = rep.advance_prefills()
                 adv_us = (time.perf_counter_ns() - t0) / 1e3
                 self._finish_pending(node, rep, completions, adv_us)
-            if route is not None and self._route_cal_us_per_key is None \
-                    and rep.sessions:
-                self._calibrate_route(rep, route)
             t0 = time.perf_counter_ns()
-            toks = rep.decode_round(route=route)
-            round_us = (time.perf_counter_ns() - t0) / 1e3
-            route_us = 0.0
+            with spans.span(spans.SERVE_DECODE_ROUND, node=node,
+                            rows=len(rep.sessions)):
+                toks = rep.decode_round(route=route)
+            share_us = (time.perf_counter_ns() - t0) / 1e3 \
+                / max(len(toks), 1)
             if route is not None and toks:
                 self.fused_rounds += 1
                 self.fused_routed_keys += len(rep.routed_owners)
                 self._note_owner_divergence(rep)
-                route_us = min((self._route_cal_us_per_key or 0.0)
-                               * len(toks), round_us)
-            share_route = route_us / max(len(toks), 1)
-            share_decode = (round_us - route_us) / max(len(toks), 1)
-            for sid, tok in toks.items():
-                trace = self.traces.get(sid)
-                if trace is not None:
-                    trace.decode_us += share_decode
-                    trace.route_us += share_route
-                self._push_token(self.sessions[sid], tok)
-                out[sid] = tok
+            with spans.span(spans.SERVE_PUSH_TOKENS):
+                for sid, tok in toks.items():
+                    trace = self.traces.get(sid)
+                    if trace is not None:
+                        trace.decode_us += share_us
+                    self._push_token(self.sessions[sid], tok)
+                    out[sid] = tok
         if self.blocks is not None and duty_turn:
             # decode rounds advance lengths across chunk boundaries;
             # ship the newly completed chunks on the same duty beat the
@@ -760,7 +766,8 @@ class ServeCluster:
             # re-replicate exactly the affected blocks BEFORE re-homing
             # sessions: the handoff fetch below must find r live copies
             self.blocks.sync()
-        self._migrate_affected()
+        with spans.span(spans.SERVE_MIGRATE) as sp:
+            spans.note(sp, moved=self._migrate_affected())
 
     def _migrate_affected(self) -> int:
         """Move exactly the sessions whose key range changed owners.
@@ -812,6 +819,20 @@ class ServeCluster:
         successor list); the admit's return value IS the next token.  The
         new slot is filled BEFORE the old one is freed, so a failed admit
         never strands a session half-migrated."""
+        with spans.span(spans.SERVE_HANDOFF, session=rec.session_id):
+            self._handoff_to(rec, group)
+
+    def _commit_move(self, rec: SessionRecord, new_owner: int) -> None:
+        """The session's ownership is now ``new_owner``'s."""
+        rec.owner = new_owner
+        rec.migrations += 1
+        self.migrated_sessions += 1
+        self._note_region(rec, migration=True)
+        trace = self.traces.get(rec.session_id)
+        if trace is not None:
+            trace._moved = True
+
+    def _handoff_to(self, rec: SessionRecord, group: List[int]) -> None:
         resident = self._session_resident(rec)
         new_owner = None
         for n in group:
@@ -840,27 +861,24 @@ class ServeCluster:
             # the old slab is gone, so nobody is decoding this session:
             # re-prefill it one fixed-shape chunk per round, OVERLAPPED
             # with the replicas' decode rounds instead of stalling them
-            if rep.begin_admit(req) is None:
+            with spans.span(spans.SERVE_ADMIT):
+                started = rep.begin_admit(req)
+            if started is None:
                 self._pending_homes[rec.session_id] = {"node": new_owner,
                                                        "t0": t0}
                 # ownership transfers NOW (the old owner is gone and the
                 # route must point at the re-prefill target); the next
                 # token arrives when the pending completes
-                rec.owner = new_owner
-                rec.migrations += 1
-                self.migrated_sessions += 1
-                self._note_region(rec, migration=True)
+                self._commit_move(rec, new_owner)
                 return
             raise AssertionError("chunkable begin_admit returned a token")
-        tok = rep.admit(req)
+        with spans.span(spans.SERVE_ADMIT):
+            tok = rep.admit(req)
         if trace is not None:
             trace.decode_us += (time.perf_counter_ns() - t0) / 1e3
         if resident:                        # clean handoff: free the slot
             self.replicas[rec.owner].evict(rec.session_id)
-        rec.owner = new_owner
-        rec.migrations += 1
-        self.migrated_sessions += 1
-        self._note_region(rec, migration=True)
+        self._commit_move(rec, new_owner)
         self._note_warm(new_owner, rec.prompt)
         self._push_token(rec, tok)
 
@@ -879,14 +897,16 @@ class ServeCluster:
             # the whole transcript, not just the last duty-beat export
             self._export_session(rec)
         t0 = time.perf_counter_ns()
-        blocks = self._fetch_blocks(rec, len(req.prompt))
+        with spans.span(spans.KV_FETCH):
+            blocks = self._fetch_blocks(rec, len(req.prompt))
         if not blocks:
             self.handoff_misses += 1
             return False
         fetch_us = (time.perf_counter_ns() - t0) / 1e3
         t1 = time.perf_counter_ns()
         try:
-            tok = rep.admit_from_blocks(req, blocks)
+            with spans.span(spans.SERVE_ADMIT):
+                tok = rep.admit_from_blocks(req, blocks)
         except Exception:
             # a torn/mismatched block import must degrade to recompute,
             # never kill the migration batch
@@ -904,10 +924,7 @@ class ServeCluster:
         # valid; anything past it (a lost block broke the run) will be
         # re-exported from the new slab on the next duty beat
         rec.exported_chunks = len(blocks)
-        rec.owner = new_owner
-        rec.migrations += 1
-        self.migrated_sessions += 1
-        self._note_region(rec, migration=True)
+        self._commit_move(rec, new_owner)
         self._note_warm(new_owner, rec.prompt)
         self._push_token(rec, tok)
         return True
@@ -915,14 +932,17 @@ class ServeCluster:
     # -- observability -----------------------------------------------------------
     def latency_report(self) -> Dict[str, float]:
         """Serve-path request-latency distribution with the queue/route/
-        decode breakdown (completed sessions only), µs.  The measured
-        twin of the request plane's network-side accounting: BENCH
-        latency rows report lookup latency, this reports what the serve
-        path adds on top of the route."""
+        decode breakdown and time to first token (completed sessions
+        only), µs.  The measured twin of the request plane's
+        network-side accounting: BENCH latency rows report lookup
+        latency, this reports what the serve path adds on top of the
+        route."""
         done = [t for t in self.traces.values() if t.done]
         if not done:
             return {"completed": 0}
         tot = np.array([t.total_us for t in done])
+        ttft = np.array([(t.first_token_ns - t.submitted_ns) / 1e3
+                         for t in done])
         return {
             "completed": len(done),
             "total_us_mean": round(float(tot.mean()), 1),
@@ -936,8 +956,8 @@ class ServeCluster:
                 float(np.mean([t.decode_us for t in done])), 1),
             "handoff_us_mean": round(
                 float(np.mean([t.handoff_us for t in done])), 1),
-            "router_route_us_per_key": round(
-                self.router.route_us_per_key, 2),
+            "ttft_us_p50": round(float(np.percentile(ttft, 50)), 1),
+            "ttft_us_p95": round(float(np.percentile(ttft, 95)), 1),
         }
 
     def stats(self) -> Dict[str, int]:

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import spans
 from repro.core.ring import hash_id
 from repro.core.ringstate import RingState
 from repro.models import Model
@@ -53,10 +54,6 @@ class SessionRouter:
         # no event subscription needed: the device table refreshes
         # lazily off the shared state's version
         self.state: RingState = membership.ring_state
-        # per-batch route-latency accounting (request-latency plane §9)
-        self.route_ns = 0
-        self.route_batches = 0
-        self.route_keys = 0
 
     @property
     def uploads(self) -> int:
@@ -64,21 +61,11 @@ class SessionRouter:
         routed against — asserted by the serve acceptance test)."""
         return self.state.upload_count
 
-    @property
-    def route_us_per_key(self) -> float:
-        """Measured mean on-device resolution cost per routed key."""
-        return self.route_ns / 1e3 / max(self.route_keys, 1)
-
     def route(self, session_ids: List[str]) -> List[int]:
         keys = np.fromiter(
             (session_key(s) for s in session_ids),
             np.uint64, len(session_ids))
-        t0 = time.perf_counter_ns()
-        owners = self.state.lookup(keys)
-        self.route_ns += time.perf_counter_ns() - t0
-        self.route_batches += 1
-        self.route_keys += len(session_ids)
-        return [int(p) for p in owners]
+        return [int(p) for p in self.state.lookup(keys)]
 
 
 def session_key(session_id: str) -> int:
@@ -129,61 +116,68 @@ def _jitted(model: Model) -> Tuple:
     hidden host sync).  Tensor-parallel groups keep returning logits
     from ``TPReplicaGroup.fns`` (the head stays vocab-sharded there, so
     the argmax needs the global array — see ``decode_round``)."""
+    from repro.kernels.ring_lookup.ops import ring_lookup_bucketed
+
     prefill = jax.jit(model.prefill)
 
     def _pick(logits):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("pick"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def _index(lengths):
+    def _step(params, cache, tokens, lengths):
         # per-slot cache positions for transformer families; lockstep
         # max-length for the rest (inactive/padding rows are length 0,
         # so they never raise the lockstep position)
-        return lengths if model.supports_per_slot_decode \
-            else jnp.max(lengths)
+        with jax.named_scope("decode_step"):
+            index = lengths if model.supports_per_slot_decode \
+                else jnp.max(lengths)
+            return model.decode_step(params, cache, tokens, index)
+
+    def _gather(idx, cache, *rows):
+        # the active slots of the cache slab (slot axis 1) and of each
+        # per-slot array; padding indices read zeros
+        with jax.named_scope("slot_gather"):
+            sub = jax.tree.map(lambda c: jnp.take(
+                c, idx, axis=1, mode="fill", fill_value=0), cache)
+            return (sub,) + tuple(jnp.take(r, idx, axis=0, mode="fill",
+                                           fill_value=0) for r in rows)
+
+    def _scatter(cache, idx, new_sub):
+        with jax.named_scope("kv_scatter"):
+            return jax.tree.map(
+                lambda c, s: c.at[:, idx].set(s, mode="drop"), cache,
+                new_sub)
+
+    def _route(khi, klo, bhi, blo, occ):
+        with jax.named_scope("route"):
+            return ring_lookup_bucketed(khi, klo, bhi, blo, occ)
 
     @jax.jit
     def decode_full(params, cache, tokens, lengths):
-        logits, new_cache = model.decode_step(params, cache, tokens,
-                                              _index(lengths))
+        logits, new_cache = _step(params, cache, tokens, lengths)
         return _pick(logits), new_cache
 
     @jax.jit
     def decode_slots(params, cache, tokens, lengths, idx):
-        sub = jax.tree.map(
-            lambda c: jnp.take(c, idx, axis=1, mode="fill", fill_value=0),
-            cache)
-        tok = jnp.take(tokens, idx, axis=0, mode="fill", fill_value=0)
-        ln = jnp.take(lengths, idx, axis=0, mode="fill", fill_value=0)
-        logits, new_sub = model.decode_step(params, sub, tok, _index(ln))
-        out_cache = jax.tree.map(
-            lambda c, s: c.at[:, idx].set(s, mode="drop"), cache, new_sub)
-        return _pick(logits), out_cache
-
-    from repro.kernels.ring_lookup.ops import ring_lookup_bucketed
+        sub, tok, ln = _gather(idx, cache, tokens, lengths)
+        logits, new_sub = _step(params, sub, tok, ln)
+        return _pick(logits), _scatter(cache, idx, new_sub)
 
     @jax.jit
     def decode_full_fused(params, cache, tokens, lengths,
                           khi, klo, bhi, blo, occ):
-        ohi, olo = ring_lookup_bucketed(khi, klo, bhi, blo, occ)
-        logits, new_cache = model.decode_step(params, cache, tokens,
-                                              _index(lengths))
+        ohi, olo = _route(khi, klo, bhi, blo, occ)
+        logits, new_cache = _step(params, cache, tokens, lengths)
         return _pick(logits), new_cache, ohi, olo
 
     @jax.jit
     def decode_slots_fused(params, cache, tokens, lengths, idx,
                            khi, klo, bhi, blo, occ):
-        qhi = jnp.take(khi, idx, axis=0, mode="fill", fill_value=0)
-        qlo = jnp.take(klo, idx, axis=0, mode="fill", fill_value=0)
-        ohi, olo = ring_lookup_bucketed(qhi, qlo, bhi, blo, occ)
-        sub = jax.tree.map(
-            lambda c: jnp.take(c, idx, axis=1, mode="fill", fill_value=0),
-            cache)
-        tok = jnp.take(tokens, idx, axis=0, mode="fill", fill_value=0)
-        ln = jnp.take(lengths, idx, axis=0, mode="fill", fill_value=0)
-        logits, new_sub = model.decode_step(params, sub, tok, _index(ln))
-        out_cache = jax.tree.map(
-            lambda c, s: c.at[:, idx].set(s, mode="drop"), cache, new_sub)
-        return _pick(logits), out_cache, ohi, olo
+        sub, tok, ln, qhi, qlo = _gather(idx, cache, tokens, lengths,
+                                         khi, klo)
+        ohi, olo = _route(qhi, qlo, bhi, blo, occ)
+        logits, new_sub = _step(params, sub, tok, ln)
+        return _pick(logits), _scatter(cache, idx, new_sub), ohi, olo
 
     prefill_chunk = jax.jit(model.prefill_chunk) \
         if model.supports_chunked_prefill else None
@@ -413,8 +407,10 @@ class Replica:
             raise RuntimeError("replica full")
         try:
             t0 = time.perf_counter_ns()
-            one = self._cache_with_blocks(blocks)
-            jax.block_until_ready(jax.tree.leaves(one)[0])
+            with spans.span(spans.KV_IMPORT, session=req.session_id,
+                            chunks=len(blocks)):
+                one = self._cache_with_blocks(blocks)
+                jax.block_until_ready(jax.tree.leaves(one)[0])
             self.import_us = (time.perf_counter_ns() - t0) / 1e3
             tok, one = self._run_chunks(req.prompt, one, start=covered)
             self._write_slot(one, slot)
@@ -577,55 +573,55 @@ class Replica:
             return {}
         act_idx = np.nonzero(self.active)[0].astype(np.int32)
         bucket = _decode_bucket(act_idx.size, self.slots)
+        full = bucket == self.slots
         ohi = olo = None
-        if bucket == self.slots:
-            # full house: the gather would be the identity permutation —
-            # step the slab directly and skip the scatter-back copy
-            # (inactive rows decode garbage at position 0, as the slab
-            # engine always did; admit rewrites the whole slot anyway)
-            if route is not None:
-                out, self.cache, ohi, olo = self._decode_full_fused(
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.lengths), jnp.asarray(self.key_hi),
-                    jnp.asarray(self.key_lo), *route)
+        with spans.span(spans.SERVE_UPLOAD_SLOTS):
+            args = [jnp.asarray(self.tokens), jnp.asarray(self.lengths)]
+            if full:
+                # full house: the gather would be the identity
+                # permutation — step the slab directly and skip the
+                # scatter-back copy (inactive rows decode garbage at
+                # position 0, as the slab engine always did; admit
+                # rewrites the whole slot anyway)
+                rows = act_idx
             else:
-                out, self.cache = self._decode_full(
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.lengths))
-            rows = act_idx
-        else:
-            idx = np.full(bucket, self.slots, np.int32)  # slots = OOB pad
-            idx[:act_idx.size] = act_idx
+                idx = np.full(bucket, self.slots, np.int32)  # OOB pad
+                idx[:act_idx.size] = act_idx
+                args.append(jnp.asarray(idx))
+                rows = np.arange(act_idx.size)
             if route is not None:
-                out, self.cache, ohi, olo = self._decode_slots_fused(
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.lengths), jnp.asarray(idx),
-                    jnp.asarray(self.key_hi), jnp.asarray(self.key_lo),
-                    *route)
+                args += [jnp.asarray(self.key_hi), jnp.asarray(self.key_lo),
+                         *route]
+        with spans.span(spans.SERVE_DISPATCH):
+            if route is not None:
+                fn = self._decode_full_fused if full \
+                    else self._decode_slots_fused
+                out, self.cache, ohi, olo = fn(self.params, self.cache, *args)
             else:
-                out, self.cache = self._decode_slots(
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.lengths), jnp.asarray(idx))
-            rows = np.arange(act_idx.size)
-        if self.group is not None:
-            # TP groups return vocab-SHARDED logits (models/tp.py keeps
-            # the head shard-local): the greedy pick needs the global
-            # array, so it runs here instead of inside the group program
-            out = jnp.argmax(out, axis=-1).astype(jnp.int32)
+                fn = self._decode_full if full else self._decode_slots
+                out, self.cache = fn(self.params, self.cache, *args)
+            if self.group is not None:
+                # TP groups return vocab-SHARDED logits (models/tp.py
+                # keeps the head shard-local): the greedy pick needs the
+                # global array, so it runs here instead of inside the
+                # group program
+                out = jnp.argmax(out, axis=-1).astype(jnp.int32)
         row_of = {int(s): int(r) for s, r in zip(act_idx, rows)}
         # the round's ONE mandatory device->host transfer: B int32
         # tokens (plus the fused path's owner words) in a single
         # device_get — logits never cross the host boundary
+        with spans.span(spans.SERVE_DEVICE_GET):
+            if ohi is not None:
+                # repro-lint: allow(RL003) the one mandatory per-round transfer
+                nxt, hi, lo = jax.device_get((out, ohi, olo))
+            else:
+                # repro-lint: allow(RL003) the one mandatory per-round transfer
+                nxt = jax.device_get(out)
         if ohi is not None:
-            # repro-lint: allow(RL003) the one mandatory per-round transfer
-            nxt, hi, lo = jax.device_get((out, ohi, olo))
             owners = (hi.astype(np.uint64) << np.uint64(32)) \
                 | lo.astype(np.uint64)
             self.routed_owners = {sid: int(owners[row_of[slot]])
                                   for sid, slot in self.sessions.items()}
-        else:
-            # repro-lint: allow(RL003) the one mandatory per-round transfer
-            nxt = jax.device_get(out)
         nxt = nxt.astype(np.int32, copy=False)
         self.tokens[act_idx, 0] = nxt[rows]
         self.lengths[act_idx] += 1

@@ -9,6 +9,7 @@ is off around these compiles: an entry compiled for a described chip
 cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -46,49 +47,55 @@ def spec(topo):
     return make
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, name=None):
+    """Compile for the described chip; ``name`` is the op name the
+    kernel's custom call must carry (what a device trace shows)."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if name is not None:
+        assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text), name
     return compiled
 
 
 def test_ring_lookup64_compiles(spec):
-    from repro.kernels.ring_lookup.kernel import ring_lookup64_pallas
+    from repro.kernels.ring_lookup.kernel import NAME64, ring_lookup64_pallas
     u32 = jnp.uint32
     _compile(lambda a, b, c, d, n: ring_lookup64_pallas(
         a, b, c, d, n, interpret=False),
         spec((4096,), u32), spec((4096,), u32), spec((1 << 20,), u32),
-        spec((1 << 20,), u32), spec((1,), jnp.int32))
+        spec((1 << 20,), u32), spec((1,), jnp.int32), name=NAME64)
 
 
 def test_ring_lookup_bucketed_compiles(spec):
-    from repro.kernels.ring_lookup.kernel import (BW,
+    from repro.kernels.ring_lookup.kernel import (BW, NAME_BUCKETED,
                                                   ring_lookup_bucketed_pallas)
     u32, nb = jnp.uint32, 8192
     _compile(lambda a, b, c, d, o: ring_lookup_bucketed_pallas(
         a, b, c, d, o, interpret=False),
         spec((4096,), u32), spec((4096,), u32), spec((nb, BW), u32),
-        spec((nb, BW), u32), spec((nb,), jnp.int32))
+        spec((nb, BW), u32), spec((nb,), jnp.int32), name=NAME_BUCKETED)
 
 
 def test_edra_tree_compiles(spec):
-    from repro.kernels.edra_tree.kernel import edra_tree_pallas
+    from repro.kernels.edra_tree.kernel import NAME, edra_tree_pallas
     p, u32 = 1 << 20, jnp.uint32
     _compile(lambda o, n, r, t, k: edra_tree_pallas(
         o, n, r, t, k, levels=20, theta=1.0, delta_avg=0.0625,
         interpret=False),
         spec((p,), u32), spec((p,), u32), spec((p,), u32),
-        spec((p,), jnp.float32), spec((p,), u32))
+        spec((p,), jnp.float32), spec((p,), u32), name=NAME)
 
 
 def test_decode_attention_compiles(spec):
-    from repro.kernels.decode_attention.kernel import decode_attention_pallas
+    from repro.kernels.decode_attention.kernel import (NAME,
+                                                      decode_attention_pallas)
     b, s, hd = 8, 2048, QWEN.resolved_head_dim
     h, hkv, bf16 = QWEN.num_heads, QWEN.num_kv_heads, jnp.bfloat16
     _compile(lambda q, k, v, n: decode_attention_pallas(
         q, k, v, n, interpret=False),
         spec((b, h, hd), bf16), spec((b, s, hkv, hd), bf16),
-        spec((b, s, hkv, hd), bf16), spec((b,), jnp.int32))
+        spec((b, s, hkv, hd), bf16), spec((b,), jnp.int32), name=NAME)
 
 
 def test_flash_attention_compiles(spec):

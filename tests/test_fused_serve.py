@@ -78,7 +78,7 @@ def test_fused_round_is_one_program_and_no_host_lookup(smoke_model):
                            fused=True)
     for r in _requests(cfg, 6, max_new=6):
         cluster.submit(r)
-    cluster.step()          # warm: traces + the one-time route calibration
+    cluster.step()          # warm: traces
     counts = {}
     busy = 0
     for rep in cluster.replicas.values():
@@ -121,26 +121,26 @@ def test_fused_tokens_identical_to_unfused(smoke_model):
 
 
 def test_fused_rounds_populate_trace_splits(smoke_model):
-    """RequestTrace must keep its route/decode split under fusion: the
-    round is one dispatch, so the split comes from the calibrated
-    per-key route cost — both legs must land nonzero."""
+    """RequestTrace keeps its route/decode split under fusion without a
+    calibration: a fused round is one dispatch, so its whole wall time
+    is decode time, and ``route_us`` stays the host walk at submit (the
+    on-device route is the ``route`` scope of a profiler trace)."""
     cfg, model, params = smoke_model
     t = [0.0]
     cluster = ServeCluster(_membership(4, t), model, params, slots=8,
                            max_len=64, fused=True)
     for r in _requests(cfg, 4, max_new=6, seed=7):
         cluster.submit(r)
-    base_route = {sid: tr.route_us for sid, tr in cluster.traces.items()}
+    at_submit = {sid: (tr.route_us, tr.decode_us)
+                 for sid, tr in cluster.traces.items()}
     cluster.run()
     assert cluster.fused_rounds > 0
-    assert cluster._route_cal_us_per_key is not None
     for sid, tr in cluster.traces.items():
+        route0, decode0 = at_submit[sid]
         assert tr.done
-        assert tr.decode_us > 0
-        # the fused rounds added route share on top of the submit walk
-        assert tr.route_us >= base_route[sid]
-    assert sum(tr.route_us - base_route[sid]
-               for sid, tr in cluster.traces.items()) > 0
+        assert route0 > 0
+        assert tr.route_us == route0          # the submit walk, no more
+        assert tr.decode_us > decode0 > 0     # admit, then fused rounds
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,7 @@
 """Churn-aware serve plane: per-slot decode correctness, DES-driven
 session migration, quarantine gateway proxying, generation restarts."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -449,24 +451,42 @@ def test_admit_prefill_failure_rolls_back_slot_allocation(smoke_model):
 
 def test_serve_path_latency_traces_breakdown(smoke_model):
     """Every completed session reports a queue+route+decode wall-clock
-    breakdown, and the cluster aggregates them (request-latency plane
-    §9: the serve path's leg of the measured experiment)."""
+    breakdown and its first-token time, a session moved by a node's
+    death its resume time, and the cluster aggregates them
+    (request-latency plane §9: the serve path's leg of the measured
+    experiment)."""
     cfg, model, params = smoke_model
     t = [0.0]
     m = _membership(4, t)
     cluster = ServeCluster(m, model, params, slots=4, max_len=48)
     for r in _requests(cfg, 6, max_new=4):
         cluster.submit(r)
+    cluster.step()
+    by_owner = {}
+    for rec in cluster.sessions.values():
+        by_owner.setdefault(rec.owner, []).append(rec.session_id)
+    victim = max(by_owner, key=lambda o: len(by_owner[o]))
+    moved_at = {sid: time.perf_counter_ns() for sid in by_owner[victim]}
+    m.fail(victim)
     cluster.run()
     report = cluster.latency_report()
     assert report["completed"] == 6
     assert report["total_us_p50"] > 0 and report["decode_us_mean"] > 0
-    for trace in cluster.traces.values():
+    assert 0 < report["ttft_us_p50"] <= report["ttft_us_p95"]
+    assert report["ttft_us_p95"] < report["total_us_p99"]
+    for sid, trace in cluster.traces.items():
         assert trace.done
         assert trace.decode_us > 0          # prefill + decode rounds
         assert trace.route_us >= 0 and trace.queue_us >= 0
         # parts are measured inside the submit->done window
         assert trace.total_us * 1.5 + 100.0 > trace.decode_us
+        assert trace.submitted_ns < trace.first_token_ns \
+            <= trace.completed_ns
+        if sid in moved_at:
+            assert cluster.sessions[sid].migrations == 1
+            assert moved_at[sid] < trace.resumed_ns <= trace.completed_ns
+        else:
+            assert trace.resumed_ns == 0
 
 
 def test_stranded_sessions_rehome_when_capacity_frees(smoke_model):
