@@ -66,6 +66,7 @@ _BUCKET_ROW = 128             # row width; must equal ring_lookup kernel.BW
 _BUCKET_TARGET = 32           # mean ids per bucket the directory aims for
 _BUCKET_MIN_N = 2048          # below this the flat scan wins (one BT tile)
 _MAX_R_BONUS = 2              # extra directory doublings before fallback
+_DELTA_ROWS = 64              # rows per delta chunk (set_bucket_rows shape)
 
 
 @dataclass(frozen=True)
@@ -106,6 +107,13 @@ def _as_u64(ids: Iterable[int]) -> np.ndarray:
     if isinstance(ids, np.ndarray):
         return ids.astype(np.uint64, copy=False)
     return np.fromiter((int(i) for i in ids), dtype=np.uint64)
+
+
+def _delta_chunk(buckets: int) -> int:
+    """Rows per delta chunk for a directory of ``buckets`` rows: at most
+    ``_DELTA_ROWS``, and 1/16 of the matrix for small directories, so a
+    padded chunk never ships much of the table."""
+    return min(_DELTA_ROWS, buckets // 16)
 
 
 @dataclass(frozen=True)
@@ -173,6 +181,7 @@ class RingState:
         self.upload_bytes = 0
         self.full_uploads = 0
         self.delta_uploads = 0
+        self.lookup_calls = {"bucketed": 0, "flat": 0}  # calls by kernel
         self.last_lookup_kernel: Optional[str] = None   # "bucketed" | "flat"
         # ownership-diff log: (active_version, arcs|None) per mutation
         # batch that moved the active view; None marks an unbounded batch.
@@ -568,7 +577,7 @@ class RingState:
     def _bits_for(cap: int) -> int:
         """Directory size for a table capacity: 2^R buckets targeting
         ``_BUCKET_TARGET`` ids each, clamped so the (B, BW) matrix fits
-        the backend's fast-memory budget."""
+        the backend's memory budget."""
         from repro.kernels.backend import bucket_budget_bytes
         b = max(64, cap // _BUCKET_TARGET)
         while b > 64 and b * _BUCKET_ROW * 8 > bucket_budget_bytes():
@@ -690,8 +699,12 @@ class RingState:
 
         Delta protocol: after the first full materialization, a sync
         ships ONLY the rows membership batches dirtied since the last
-        sync, as one scatter-update per array — device maintenance
-        traffic is O(touched buckets) per EDRA batch, never O(n)."""
+        sync — device maintenance traffic is O(touched buckets) per EDRA
+        batch, never O(n).  The rows go in chunks of one fixed size per
+        directory (``_delta_chunk``) through ``set_bucket_rows``, the
+        last chunk padded by repeating its final row, so any number of
+        dirty rows runs one compiled program.  ``upload_bytes`` counts
+        the padded chunks and their row indices."""
         self._enable_buckets()
         if not self._bkt_valid:
             return None
@@ -702,8 +715,14 @@ class RingState:
 
         nb = 1 << self._bkt_bits
         full = self._bkt_dev is None or self._bkt_dev_bits != self._bkt_bits
-        rows = np.arange(nb) if full else np.nonzero(self._bkt_dirty)[0]
-        nbytes = int(rows.size) * (_BUCKET_ROW * 8 + 4)
+        if full:
+            rows = np.arange(nb)
+            nbytes = nb * (_BUCKET_ROW * 8 + 4)
+        else:
+            rows = np.nonzero(self._bkt_dirty)[0].astype(np.int32)
+            chunk = _delta_chunk(nb)
+            rows = np.pad(rows, (0, -rows.size % chunk), mode="edge")
+            nbytes = int(rows.size) * (_BUCKET_ROW * 8 + 8)   # + index
         with spans.span(spans.RING_TABLE_UPLOAD, bytes=nbytes):
             hi, lo = self._build_rows(rows)
             if full:
@@ -712,12 +731,13 @@ class RingState:
                 self._bkt_dev_bits = self._bkt_bits
                 self.full_uploads += 1
             else:
-                bhi, blo, occ = self._bkt_dev
-                at = jnp.asarray(rows.astype(np.int32))
-                self._bkt_dev = (
-                    bhi.at[at].set(jnp.asarray(hi)),
-                    blo.at[at].set(jnp.asarray(lo)),
-                    occ.at[at].set(jnp.asarray(self._bkt_occ[rows])))
+                from repro.kernels.ring_lookup.ops import set_bucket_rows
+                occ = self._bkt_occ[rows]
+                for at in range(0, rows.size, chunk):
+                    part = slice(at, at + chunk)
+                    self._bkt_dev = set_bucket_rows(
+                        *self._bkt_dev, rows[part], hi[part], lo[part],
+                        occ[part])
                 self.delta_uploads += 1
         self.upload_bytes += nbytes
         self.upload_count += 1
@@ -739,6 +759,7 @@ class RingState:
             "mean_occupancy": float(occ.mean()) if occ.size else 0.0,
             "directory_bytes": nb * 4,
             "matrix_bytes": nb * _BUCKET_ROW * 8,
+            "lookup_calls": dict(self.lookup_calls),
         }
 
     # -- device-resident table -------------------------------------------------
@@ -818,6 +839,7 @@ class RingState:
                                                     interpret=interpret)
                     ohi, olo = np.asarray(ohi), np.asarray(olo)
                 self.last_lookup_kernel = "bucketed"
+                self.lookup_calls["bucketed"] += 1
                 with spans.span(spans.RING_OWNERS):
                     return (ohi.astype(np.uint64) << _WORD) \
                         | olo.astype(np.uint64)
@@ -828,6 +850,7 @@ class RingState:
                                            use_pallas=use_pallas,
                                            interpret=interpret))
         self.last_lookup_kernel = "flat"
+        self.lookup_calls["flat"] += 1
         with spans.span(spans.RING_OWNERS):
             return act[idx]
 

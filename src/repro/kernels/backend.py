@@ -90,18 +90,16 @@ def bucket_budget_bytes() -> int:
     flat-scan kernel — once the (B, BW) matrix would outgrow this
     budget.  Interpreted backends (CPU tests, CI) only burn host RAM.
 
-    The compiled-path constant (8 MB) was sized when the kernel held
-    the whole matrix in one VMEM block (~16 MiB per TPU core).  The
-    kernel now gathers each query's row in device memory (HBM) and
-    streams only row blocks through VMEM, so the constant is stricter
-    than the kernel needs: at n = 10^6 it forces the flat scan.  It is
-    validated against the device's reported memory: a small
-    accelerator caps the budget at 1/16 of its actual capacity.
+    On a compiled backend the matrix lives in device memory (HBM): the
+    kernel's wrapper gathers each query's row there and streams only
+    row blocks through VMEM.  The budget is 1/16 of the device's
+    reported memory (1 GiB on a 16 GiB v5e, where n = 10^6 takes a
+    32 MiB directory of 32,768 rows), with a 1 MB floor.  A backend
+    that reports no memory gets 8 MB.
     """
     if default_interpret():
         return 256 << 20
-    budget = 8 << 20
     mem = _device_memory_bytes()
-    if mem is not None:
-        budget = min(budget, max(mem // 16, 1 << 20))
-    return budget
+    if mem is None:
+        return 8 << 20
+    return max(mem // 16, 1 << 20)

@@ -68,3 +68,19 @@ def ring_lookup_bucketed(keys_hi: jax.Array, keys_lo: jax.Array,
             keys_hi, keys_lo, bkt_hi, bkt_lo, occ,
             interpret=resolve_interpret(interpret))
     return ring_lookup_bucketed_ref(keys_hi, keys_lo, bkt_hi, bkt_lo, occ)
+
+
+@partial(jax.jit, donate_argnums=(0, 1, 2))
+def set_bucket_rows(bkt_hi: jax.Array, bkt_lo: jax.Array, occ: jax.Array,
+                    rows: jax.Array, rows_hi: jax.Array, rows_lo: jax.Array,
+                    rows_occ: jax.Array):
+    """Rewrite bucket rows of the device table in place: (C,) int32 row
+    indices, their (C, BW) hi/lo words and (C,) occupancies.
+
+    The three table arrays are donated, so a delta costs no copy of the
+    matrix.  Callers ship a fixed chunk C per directory size, padding a
+    short chunk by repeating one of its rows with that row's own data:
+    duplicate indices then write identical values, and every delta of
+    a directory runs one compiled program."""
+    return (bkt_hi.at[rows].set(rows_hi), bkt_lo.at[rows].set(rows_lo),
+            occ.at[rows].set(rows_occ))

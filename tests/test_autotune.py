@@ -114,8 +114,8 @@ def test_shape_bucket_rounds_to_pow2():
 
 
 # ---------------------------------------------------------------------------
-# bucket_budget_bytes: device-memory validation (regression: the 8 MB
-# compiled-path constant must yield to a smaller device's reported memory)
+# bucket_budget_bytes: a share of the device's reported memory on a
+# compiled backend, 8 MB where the backend reports none
 # ---------------------------------------------------------------------------
 
 @pytest.fixture
@@ -142,7 +142,7 @@ def test_budget_capped_by_small_device(budget_caches, monkeypatch):
     assert backend.bucket_budget_bytes() == 4 << 20      # mem // 16
     backend.bucket_budget_bytes.cache_clear()
     monkeypatch.setattr(backend, "_device_memory_bytes", lambda: 32 << 30)
-    assert backend.bucket_budget_bytes() == 8 << 20      # constant wins
+    assert backend.bucket_budget_bytes() == 2 << 30      # mem // 16
     backend.bucket_budget_bytes.cache_clear()
     monkeypatch.setattr(backend, "_device_memory_bytes", lambda: 4 << 20)
     assert backend.bucket_budget_bytes() == 1 << 20      # floor
@@ -200,7 +200,7 @@ def test_default_cache_lives_in_the_checkout(monkeypatch):
 ])
 def test_device_memory_never_hides_a_tpu(monkeypatch, platform, stats, expect):
     """Off a TPU, missing memory stats read as unknown; on a TPU they are
-    an error, not a silent fallback to the 8 MB constant."""
+    an error, not a silent fallback to the 8 MB budget."""
     import jax
 
     class Dev:

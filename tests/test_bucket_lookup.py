@@ -218,17 +218,26 @@ def test_randomized_churn_uploads_scale_with_batches_not_n():
 
 def test_delta_sync_equals_full_rebuild():
     """After heavy churn, the scatter-maintained device rows must be
-    bit-identical to a from-scratch materialization of the same view."""
+    bit-identical to a from-scratch materialization of the same view,
+    with every delta padded to whole chunks of one shape."""
+    from repro.core.ringstate import _delta_chunk
+
     state = RingState(_rand_ids(3000))
     state.lookup(RNG.integers(0, 2**64, size=64, dtype=np.uint64))
-    for i in range(6):
+    sizes = (40, 1, 7, 25, 3, 60)       # dirty-row counts that pad unevenly
+    for i, k in enumerate(sizes):
         live = state.active_ids()
         evs = [Event(subject_id=int(p), kind="leave", seq=i)
-               for p in live[RNG.integers(0, live.size, size=40)]]
+               for p in live[RNG.integers(0, live.size, size=k)]]
         evs += [Event(subject_id=int(p), kind="join", seq=i)
-                for p in _rand_ids(40)]
+                for p in _rand_ids(k)]
+        b0 = state.upload_bytes
         state.apply_events(evs)
         state.lookup(RNG.integers(0, 2**64, size=64, dtype=np.uint64))
+        chunk_bytes = _delta_chunk(state.bucket_stats()["buckets"]) \
+            * (_BUCKET_ROW * 8 + 8)
+        assert (state.upload_bytes - b0) % chunk_bytes == 0
+    assert state.delta_uploads == len(sizes)
     fresh = RingState(state.active_ids())
     fresh._enable_buckets()
     incr = state.device_bucket_table()
@@ -242,6 +251,75 @@ def test_delta_sync_equals_full_rebuild():
         np.testing.assert_array_equal(
             state.lookup(keys, use_buckets=True),
             fresh.lookup(keys, use_buckets=True))
+
+
+def test_delta_compiles_once_per_directory_size():
+    """Messages that dirty 1 to 100+ rows all run one compiled delta
+    program per directory size: the chunk shape depends on the
+    directory alone."""
+    from repro.kernels.ring_lookup.ops import set_bucket_rows
+
+    set_bucket_rows.clear_cache()
+    rng = np.random.default_rng(7)
+    keys = rng.integers(0, 2**64, size=256, dtype=np.uint64)
+    for n, compiled in ((16384, 1), (3000, 2)):
+        state = RingState(np.unique(rng.integers(0, 2**64, size=n,
+                                                 dtype=np.uint64)))
+        state.lookup(keys)
+        for i, k in enumerate((1, 8, 40, 100)):
+            live = state.active_ids()
+            evs = [Event(subject_id=int(p), kind="leave", seq=i)
+                   for p in live[rng.integers(0, live.size, size=k)]]
+            evs += [Event(subject_id=int(p), kind="join", seq=i)
+                    for p in rng.integers(0, 2**64, size=k, dtype=np.uint64)]
+            state.apply_events(evs)
+            np.testing.assert_array_equal(state.lookup(keys),
+                                          _oracle(state, keys))
+        assert state.delta_uploads == 4
+        assert set_bucket_rows._cache_size() == compiled
+
+
+@pytest.fixture
+def chip_budget(monkeypatch):
+    """The compiled-path bucket budget of a 16 GiB device, with the
+    kernels still interpreted on this backend."""
+    from repro.kernels import backend
+
+    with monkeypatch.context() as m:
+        m.setattr(backend, "default_interpret", lambda: False)
+        m.setattr(backend, "_device_memory_bytes", lambda: 16 << 30)
+        backend.bucket_budget_bytes.cache_clear()
+        budget = backend.bucket_budget_bytes()
+    backend.bucket_budget_bytes.cache_clear()
+    monkeypatch.setattr(backend, "bucket_budget_bytes", lambda: budget)
+    return budget
+
+
+def test_million_peer_table_resolves_through_buckets_on_chip_budget(
+        chip_budget):
+    """n = 10^6 on a 16 GiB device arms a valid 32,768-bucket index and
+    keeps it under EDRA messages: every owner is the successor and no
+    call takes the flat scan."""
+    assert chip_budget == 1 << 30
+    rng = np.random.default_rng(14)
+    state = RingState(np.unique(rng.integers(0, 2**64, size=1_000_000,
+                                             dtype=np.uint64)))
+    keys = rng.integers(0, 2**64, size=512, dtype=np.uint64)
+    np.testing.assert_array_equal(state.lookup(keys), _oracle(state, keys))
+    for i in range(3):               # messages of 52-53 events, as in D1HT
+        live = state.active_ids()
+        evs = [Event(subject_id=int(p), kind="leave", seq=i)
+               for p in live[rng.integers(0, live.size, size=26)]]
+        evs += [Event(subject_id=int(p), kind="join", seq=i)
+                for p in rng.integers(0, 2**64, size=27, dtype=np.uint64)]
+        state.apply_events(evs)
+        np.testing.assert_array_equal(state.lookup(keys),
+                                      _oracle(state, keys))
+    stats = state.bucket_stats()
+    assert stats["valid"] and stats["buckets"] == 32768
+    assert stats["max_occupancy"] < _BUCKET_ROW
+    assert stats["lookup_calls"] == {"bucketed": 4, "flat": 0}
+    assert state.delta_uploads == 3 and state.full_uploads == 1
 
 
 def test_empty_table_raises_lookup_error():

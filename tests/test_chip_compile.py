@@ -67,13 +67,17 @@ def test_ring_lookup64_compiles(spec):
         spec((1 << 20,), u32), spec((1,), jnp.int32), name=NAME64)
 
 
-def test_ring_lookup_bucketed_compiles(spec):
+# the ring cells' shapes: a 2^20-key bulk call and a 1,600-key clients
+# call on the 32,768-row directory of n = 10^6
+@pytest.mark.parametrize("q, nb", [(4096, 8192), (1 << 20, 32768),
+                                   (1600, 32768)])
+def test_ring_lookup_bucketed_compiles(spec, q, nb):
     from repro.kernels.ring_lookup.kernel import (BW, NAME_BUCKETED,
                                                   ring_lookup_bucketed_pallas)
-    u32, nb = jnp.uint32, 8192
+    u32 = jnp.uint32
     _compile(lambda a, b, c, d, o: ring_lookup_bucketed_pallas(
         a, b, c, d, o, interpret=False),
-        spec((4096,), u32), spec((4096,), u32), spec((nb, BW), u32),
+        spec((q,), u32), spec((q,), u32), spec((nb, BW), u32),
         spec((nb, BW), u32), spec((nb,), jnp.int32), name=NAME_BUCKETED)
 
 
