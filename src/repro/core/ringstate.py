@@ -182,6 +182,9 @@ class RingState:
         self.full_uploads = 0
         self.delta_uploads = 0
         self.lookup_calls = {"bucketed": 0, "flat": 0}  # calls by kernel
+        # key uploads and result fetches of lookup calls (table syncs
+        # count in upload_count): 1 each way per call
+        self.lookup_transfers = {"up": 0, "down": 0}
         self.last_lookup_kernel: Optional[str] = None   # "bucketed" | "flat"
         # ownership-diff log: (active_version, arcs|None) per mutation
         # batch that moved the active view; None marks an unbounded batch.
@@ -760,6 +763,7 @@ class RingState:
             "directory_bytes": nb * 4,
             "matrix_bytes": nb * _BUCKET_ROW * 8,
             "lookup_calls": dict(self.lookup_calls),
+            "lookup_transfers": dict(self.lookup_transfers),
         }
 
     # -- device-resident table -------------------------------------------------
@@ -819,36 +823,42 @@ class RingState:
         return owners
 
     def _lookup(self, keys, use_pallas, interpret, use_buckets):
+        """One buffer each way (DESIGN.md §3): the keys go up as the
+        (2Q,) little-endian uint32 words of their own bytes, the bucketed
+        owners come down in the same word order and are viewed as
+        uint64, so the host does no arithmetic over keys or owners.  The
+        bucketed result is a read-only view of the fetched buffer."""
         import jax.numpy as jnp
 
         act = self.active_ids()
         if not act.size:
             raise LookupError("empty routing table")
         with spans.span(spans.RING_UPLOAD_KEYS):
-            khi = jnp.asarray((keys >> _WORD).astype(np.uint32))
-            klo = jnp.asarray((keys & _LO_MASK).astype(np.uint32))
+            words = jnp.asarray(np.ascontiguousarray(keys, "<u8").view("<u4"))
+            self.lookup_transfers["up"] += 1
         if use_buckets is None:
             use_buckets = act.size >= _BUCKET_MIN_N
         if use_buckets:
             dev = self.device_bucket_table()
             if dev is not None:
-                from repro.kernels.ring_lookup.ops import ring_lookup_bucketed
+                from repro.kernels.ring_lookup.ops import \
+                    ring_lookup_bucketed_words
                 with spans.span(spans.RING_KERNEL):
-                    ohi, olo = ring_lookup_bucketed(khi, klo, *dev,
-                                                    use_pallas=use_pallas,
-                                                    interpret=interpret)
-                    ohi, olo = np.asarray(ohi), np.asarray(olo)
+                    out = np.asarray(ring_lookup_bucketed_words(
+                        words, *dev, use_pallas=use_pallas,
+                        interpret=interpret))
+                    self.lookup_transfers["down"] += 1
                 self.last_lookup_kernel = "bucketed"
                 self.lookup_calls["bucketed"] += 1
                 with spans.span(spans.RING_OWNERS):
-                    return (ohi.astype(np.uint64) << _WORD) \
-                        | olo.astype(np.uint64)
-        from repro.kernels.ring_lookup.ops import ring_lookup64
+                    return out.view("<u8")
+        from repro.kernels.ring_lookup.ops import ring_lookup64_words
         thi, tlo, n = self.device_table()
         with spans.span(spans.RING_KERNEL):
-            idx = np.asarray(ring_lookup64(khi, klo, thi, tlo, n,
-                                           use_pallas=use_pallas,
-                                           interpret=interpret))
+            idx = np.asarray(ring_lookup64_words(words, thi, tlo, n,
+                                                 use_pallas=use_pallas,
+                                                 interpret=interpret))
+            self.lookup_transfers["down"] += 1
         self.last_lookup_kernel = "flat"
         self.lookup_calls["flat"] += 1
         with spans.span(spans.RING_OWNERS):

@@ -5,6 +5,8 @@ from functools import partial
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
+from jax import lax
 
 from ..backend import resolve_interpret
 from .kernel import (ring_lookup64_pallas, ring_lookup_bucketed_pallas,
@@ -68,6 +70,55 @@ def ring_lookup_bucketed(keys_hi: jax.Array, keys_lo: jax.Array,
             keys_hi, keys_lo, bkt_hi, bkt_lo, occ,
             interpret=resolve_interpret(interpret))
     return ring_lookup_bucketed_ref(keys_hi, keys_lo, bkt_hi, bkt_lo, occ)
+
+
+def _split_words(words: jax.Array):
+    """(2Q,) uint32 little-endian words of (Q,) uint64 ids -> ((Q,) hi,
+    (Q,) lo).  Two stride-2 slices of the 1-D buffer: no (Q, 2) array,
+    whose minor dimension of 2 the TPU would pad to 128 lanes."""
+    n = words.shape[0]
+    return (lax.slice(words, (1,), (n,), (2,)),
+            lax.slice(words, (0,), (n,), (2,)))
+
+
+def _join_words(hi: jax.Array, lo: jax.Array) -> jax.Array:
+    """Inverse of ``_split_words`` on lane-dense shapes: rows of 64 words
+    of each, transposed, stacked so that the pairs interleave along
+    sublanes, and transposed back into (2Q,) (lo, hi) words."""
+    q = lo.shape[0]
+    pad = -q % 64
+    lo = jnp.pad(lo, (0, pad)).reshape(-1, 64).T
+    hi = jnp.pad(hi, (0, pad)).reshape(-1, 64).T
+    return jnp.stack([lo, hi], axis=1).reshape(128, -1).T.reshape(-1)[:2 * q]
+
+
+@partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+def ring_lookup64_words(words: jax.Array, table_hi: jax.Array,
+                        table_lo: jax.Array, n: jax.Array, *,
+                        use_pallas: bool = True,
+                        interpret: Optional[bool] = None) -> jax.Array:
+    """``ring_lookup64`` on keys as one (2Q,) uint32 buffer: the bytes of
+    a little-endian (Q,) uint64 array, split into words on the device."""
+    hi, lo = _split_words(words)
+    return ring_lookup64(hi, lo, table_hi, table_lo, n,
+                         use_pallas=use_pallas, interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+def ring_lookup_bucketed_words(words: jax.Array, bkt_hi: jax.Array,
+                               bkt_lo: jax.Array, occ: jax.Array, *,
+                               use_pallas: bool = True,
+                               interpret: Optional[bool] = None) -> jax.Array:
+    """``ring_lookup_bucketed`` with one buffer each way (DESIGN.md §3):
+    keys in as the (2Q,) uint32 bytes of a little-endian (Q,) uint64
+    array, owners out in the same word order, so the host uploads a view
+    of its keys and views the fetched owners as uint64, with no word
+    arithmetic on either side."""
+    hi, lo = _split_words(words)
+    ohi, olo = ring_lookup_bucketed(hi, lo, bkt_hi, bkt_lo, occ,
+                                    use_pallas=use_pallas,
+                                    interpret=interpret)
+    return _join_words(ohi, olo)
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2))

@@ -117,6 +117,43 @@ if _HYP:
         assert state.upload_count - u0 <= 3 * len(batches)
 
 
+    # the word-buffer protocol at the kernel's block edges (BQ = 1024)
+    _WORD_QS = (1, 7, 1023, 1025)
+
+    @settings(max_examples=12, deadline=None)
+    @given(any_ids, st.sampled_from(_WORD_QS), st.data())
+    def test_word_buffer_lookup_matches_bisect_and_word_pairs(ids, q, data):
+        """One (2Q,) buffer each way equals the bisect oracle and the
+        two-word ``ring_lookup_bucketed`` call, on keys that straddle
+        the 32-bit word boundary and on exact ids and id + 1, with a
+        quarantined peer."""
+        state = RingState(ids)
+        state.set_quarantined(int(data.draw(st.sampled_from(sorted(ids)))),
+                              True)
+        act = state.active_ids()
+        special = np.concatenate([
+            np.array([0, 2**32 - 1, 2**32, 2**64 - 1], np.uint64),
+            act, act + np.uint64(1)])
+        rng = np.random.default_rng(data.draw(u32))
+        keys = np.resize(np.concatenate(
+            [special, rng.integers(0, 2**64, q, dtype=np.uint64)]), q)
+        got = state.lookup(keys, use_buckets=True)
+        np.testing.assert_array_equal(got, _oracle(state, keys))
+        dev = state.device_bucket_table()
+        if dev is not None:
+            import jax.numpy as jnp
+
+            from repro.kernels.ring_lookup.ops import (
+                ring_lookup_bucketed, ring_lookup_bucketed_words)
+            words = np.asarray(ring_lookup_bucketed_words(
+                jnp.asarray(keys.view("<u4")), *dev))
+            ohi, olo = ring_lookup_bucketed(
+                jnp.asarray((keys >> np.uint64(32)).astype(np.uint32)),
+                jnp.asarray(keys.astype(np.uint32)), *dev)
+            np.testing.assert_array_equal(words[1::2], np.asarray(ohi))
+            np.testing.assert_array_equal(words[0::2], np.asarray(olo))
+
+
 # ---------------------------------------------------------------------------
 # always-run randomized + deterministic coverage of the same invariants
 # ---------------------------------------------------------------------------
@@ -133,6 +170,34 @@ def test_forced_bucket_path_matches_bisect(n):
     keys = RNG.integers(0, 2**64, size=512, dtype=np.uint64)
     _check_all_paths(state, keys)
     _check_all_paths(state, _boundary_keys(state))
+
+
+@pytest.mark.parametrize("q", [1, 7, 1023, 1025])
+@pytest.mark.parametrize("use_buckets", [True, False])
+def test_lookup_returns_q_uint64_owners(q, use_buckets):
+    state = RingState(_rand_ids(3000))
+    keys = RNG.integers(0, 2**64, size=q, dtype=np.uint64)
+    got = state.lookup(keys, use_buckets=use_buckets)
+    assert got.shape == (q,) and got.dtype == np.uint64
+    np.testing.assert_array_equal(got, _oracle(state, keys))
+    assert state.last_lookup_kernel == ("bucketed" if use_buckets else "flat")
+
+
+def test_lookup_transfers_one_buffer_each_way_per_call():
+    """A bucketed call moves its keys up and its owners down as one
+    buffer each; membership syncs between calls count elsewhere."""
+    state = RingState(_rand_ids(3000))
+    keys = RNG.integers(0, 2**64, size=300, dtype=np.uint64)
+    assert state.lookup_transfers == {"up": 0, "down": 0}
+    for i in range(3):
+        state.lookup(keys)
+        assert state.lookup_transfers == {"up": i + 1, "down": i + 1}
+        live = state.active_ids()
+        state.apply_events([Event(subject_id=int(live[i]), kind="leave",
+                                  seq=i)])
+    stats = state.bucket_stats()
+    assert stats["lookup_calls"] == {"bucketed": 3, "flat": 0}
+    assert stats["lookup_transfers"] == {"up": 3, "down": 3}
 
 
 def test_auto_dispatch_threshold():

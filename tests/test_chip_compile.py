@@ -81,6 +81,47 @@ def test_ring_lookup_bucketed_compiles(spec, q, nb):
         spec((nb, BW), u32), spec((nb,), jnp.int32), name=NAME_BUCKETED)
 
 
+# a minor dimension of 2 ((Q, 2) word pairs) is tiled to 128 lanes on the
+# TPU, 64 times the bytes, and buffer reuse can hide it from the temp
+# total: no array of the lookup program may have one
+_MINOR_TWO = re.compile(r"\[(\d+,)*2\]\{")
+
+
+@pytest.mark.parametrize("q, nb", [(1 << 20, 32768), (1600, 32768)])
+def test_ring_lookup_bucketed_words_compiles(spec, q, nb):
+    """The one-buffer-each-way lookup program needs no more temporaries
+    than the two-word program at the ring cells' shapes, holds no (Q, 2)
+    array, and keeps a module name the ring rooflines select."""
+    from repro.kernels.ring_lookup.kernel import BW, NAME_BUCKETED
+    from repro.kernels.ring_lookup.ops import (ring_lookup_bucketed,
+                                               ring_lookup_bucketed_words)
+    u32, table = jnp.uint32, (spec((nb, BW), jnp.uint32),
+                              spec((nb, BW), jnp.uint32),
+                              spec((nb,), jnp.int32))
+    words = ring_lookup_bucketed_words.lower(
+        spec((2 * q,), u32), *table, interpret=False).compile()
+    pair = ring_lookup_bucketed.lower(
+        spec((q,), u32), spec((q,), u32), *table, interpret=False).compile()
+    text = words.as_text()
+    assert text.startswith("HloModule jit_ring_lookup_bucketed_words")
+    assert re.search(rf"%{NAME_BUCKETED}(\.\d+)? = .*tpu_custom_call", text)
+    assert not _MINOR_TWO.search(text)
+    temp = words.memory_analysis().temp_size_in_bytes
+    assert temp <= pair.memory_analysis().temp_size_in_bytes + (1 << 20)
+
+
+def test_ring_lookup64_words_compiles(spec):
+    from repro.kernels.ring_lookup.kernel import NAME64
+    from repro.kernels.ring_lookup.ops import ring_lookup64_words
+    u32 = jnp.uint32
+    text = ring_lookup64_words.lower(
+        spec((8192,), u32), spec((1 << 20,), u32), spec((1 << 20,), u32),
+        spec((1,), jnp.int32), interpret=False).compile().as_text()
+    assert text.startswith("HloModule jit_ring_lookup64_words")
+    assert re.search(rf"%{NAME64}(\.\d+)? = .*tpu_custom_call", text)
+    assert not _MINOR_TWO.search(text)
+
+
 def test_edra_tree_compiles(spec):
     from repro.kernels.edra_tree.kernel import NAME, edra_tree_pallas
     p, u32 = 1 << 20, jnp.uint32
